@@ -27,7 +27,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import time
@@ -40,6 +39,7 @@ from repro.analysis import vmem
 from repro.core.bsr import BSR, magnitude_block_mask
 from repro.data.datasets import DatasetSpec, synthesize
 from repro.kernels import autotune, ops
+from repro.launch import compile_cache
 
 
 def _time(fn, *args, reps: int = 5):
@@ -175,7 +175,7 @@ def run(seed: int = 0):
     prep = ops.prepare_incrs(inc, pad_rows_to=128)
 
     def _model(variant, n_cols, bm=128, bn=128):
-        mrows, nsec, smax = prep.idx.shape
+        nsec, mrows, smax = prep.idx.shape
         np_ = -(-n_cols // bn) * bn
         cost = autotune.kernel_cost(variant, mrows, np_, n_sections=nsec,
                                     smax=smax, section=prep.section,
@@ -395,84 +395,40 @@ def run(seed: int = 0):
                                       and de_rec["oracle_correct"]),
     }
 
-    # Row-sharded fused SpMM across fake host devices: each count runs in a
-    # subprocess (XLA fixes the device count at backend init, so the parent
-    # process cannot revisit it). Same operand as the fused rows above.
-    # Interpret-mode fake devices SHARE one host, so this tracks the
-    # shard_map data path's overhead trajectory, not real-chip scaling —
-    # the per-count ratios are what matters across PRs.
-    sharded = _sharded_scaling(spec, seed)
+    # Row-sharded fused SpMM over the first 1, 2, 4, ... of this process's
+    # devices (all in this process: a child process could not reach a chip
+    # the parent already holds). Same operand as the fused rows above. On
+    # fake CPU devices (XLA_FLAGS=--xla_force_host_platform_device_count)
+    # the shards share one host, so the rows track the shard_map data
+    # path's overhead, not scaling across chips.
+    sharded = _sharded_scaling(inc, bk)
     for n_dev, us in sorted(sharded.items()):
         rows.append((f"incrs_spmm_sharded_dev{n_dev}", us,
                      f"devices={n_dev};rows_per_shard={spec.m // n_dev}"))
-    if sharded:
-        base = sharded.get(1)
-        comparisons["incrs_spmm_sharded"] = {
-            "us_per_device_count": {str(k): v
-                                    for k, v in sorted(sharded.items())},
-            "relative_to_1dev": {str(k): (base / v if base else None)
-                                 for k, v in sorted(sharded.items())},
-            "workload": f"{spec.m}x{spec.n} d={spec.density} @ 256 cols, "
-                        f"row-sharded over fake CPU devices",
-        }
+    base = sharded[1]
+    comparisons["incrs_spmm_sharded"] = {
+        "us_per_device_count": {str(k): v
+                                for k, v in sorted(sharded.items())},
+        "relative_to_1dev": {str(k): base / v
+                             for k, v in sorted(sharded.items())},
+        "workload": f"{spec.m}x{spec.n} d={spec.density} @ 256 cols, "
+                    f"row-sharded over {jax.default_backend()} devices",
+    }
     return rows, comparisons, models
 
 
-_SHARDED_BENCH = """
-import time
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh
-from repro.core.incrs import InCRS
-from repro.data.datasets import DatasetSpec, synthesize
-from repro.kernels import ops
-spec = DatasetSpec("kb", {m}, {n}, {density})
-inc = InCRS.from_crs(synthesize(spec, {seed}))
-rng = np.random.default_rng({seed})
-b = jnp.asarray(rng.normal(size=(spec.n, 256)).astype(np.float32))
-mesh = Mesh(np.asarray(jax.devices()).reshape({n_dev}), ("data",))
-prep = ops.prepare_incrs_sharded(inc, mesh, pad_rows_to=32)
-out = ops.spmm(prep, b)
-jax.block_until_ready(out)
-best = float("inf")
-for _ in range(5):
-    t0 = time.perf_counter()
-    jax.block_until_ready(ops.spmm(prep, b))
-    best = min(best, time.perf_counter() - t0)
-print("US", best * 1e6)
-"""
-
-
-def _sharded_scaling(spec, seed, counts=(1, 2, 4, 8)):
-    """Time the row-sharded fused SpMM at several fake-device counts, one
-    subprocess per count. Returns {n_devices: best_us} (counts whose
-    subprocess fails are skipped with a warning, never fatal)."""
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def _sharded_scaling(inc, b):
+    """Time the row-sharded fused SpMM on the first 1, 2, 4, ... devices
+    of this process. Returns {n_devices: best_us}; a failure is fatal."""
+    from jax.sharding import Mesh
+    devices = jax.devices()
     out = {}
-    for n_dev in counts:
-        env = dict(
-            os.environ,
-            XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
-            PYTHONPATH=os.path.join(here, "src") + (
-                os.pathsep + os.environ["PYTHONPATH"]
-                if os.environ.get("PYTHONPATH") else ""))
-        code = _SHARDED_BENCH.format(m=spec.m, n=spec.n,
-                                     density=spec.density, seed=seed,
-                                     n_dev=n_dev)
-        try:
-            res = subprocess.run([sys.executable, "-c", code], env=env,
-                                 capture_output=True, text=True, timeout=600)
-        except subprocess.TimeoutExpired:
-            print(f"warn,incrs_spmm_sharded_dev{n_dev},timeout",
-                  file=sys.stderr)
-            continue
-        if res.returncode != 0:
-            print(f"warn,incrs_spmm_sharded_dev{n_dev},failed:"
-                  f"{res.stderr[-500:]}", file=sys.stderr)
-            continue
-        us = [ln.split()[1] for ln in res.stdout.splitlines()
-              if ln.startswith("US ")]
-        if us:
-            out[n_dev] = float(us[0])
+    n_dev = 1
+    while n_dev <= len(devices):
+        mesh = Mesh(np.asarray(devices[:n_dev]), ("data",))
+        prep = ops.prepare_incrs_sharded(inc, mesh, pad_rows_to=32)
+        out[n_dev] = _time(lambda x: ops.spmm(prep, x), b)
+        n_dev *= 2
     return out
 
 
@@ -520,6 +476,7 @@ def main(argv=None):
                     help="fail (exit 1) if any kernel row regresses >25%% "
                          "vs this committed record (machine-relative)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     rows, comparisons, models = run()
     for name, us, derived in rows:
         print(f"kernel,{name},{us:.0f}us,{derived}")

@@ -17,6 +17,8 @@ def _section(title):
 
 def main() -> None:
     t0 = time.time()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from . import (fig3_cache_sim, fig4_sweeps, fig5_architectures,
                    kernel_bench, table1_ma_complexity, table2_incrs)
     _section("Table I: MA complexity per format")

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core.incrs import InCRS
 from repro.kernels import ops
+from repro.launch import compile_cache
 from repro.serve.engine import SpMMEngine, SpMMRequest
 
 # Mixed request widths (cols), weighted toward narrow requests with a
@@ -232,6 +233,7 @@ def main(argv=None):
                     help="small trace for CI")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     rows, comparisons = run(seed=args.seed, smoke=args.smoke)
     for row in rows:
         if "rps" in row:

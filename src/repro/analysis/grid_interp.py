@@ -650,7 +650,7 @@ class Geometry:
 
 _INCRS_ENV = dict(m=16, mp=16, bm=8, n=256, bn=128, n_sections=3,
                   smax=4, section=16, k=48)
-_INCRS_OPS = ((16, 3, 4), (16, 3, 4), (48, 256))
+_INCRS_OPS = ((3, 16, 4), (3, 16, 4), (48, 256))
 
 GEOMETRIES: Dict[str, Geometry] = {
     "incrs_spmm": Geometry(
@@ -677,7 +677,7 @@ GEOMETRIES: Dict[str, Geometry] = {
         "index_match_spmm.py", "index_match_spmm",
         dict(m=16, n=16, bm=8, bn=8, rounds=16, n_rounds=2, rmax_a=3,
              rmax_b=3),
-        ((16, 2, 3), (16, 2, 3), (16, 2, 3), (16, 2, 3))),
+        ((2, 16, 3), (2, 16, 3), (2, 16, 3), (2, 16, 3))),
     "flash_attention": Geometry(
         "flash_attention.py", "flash_attention",
         dict(lanes=4, g=2, sq=16, sk=16, hd=8, bq=8, bk=8, window=None,
@@ -686,12 +686,12 @@ GEOMETRIES: Dict[str, Geometry] = {
     "incrs_gather": Geometry(
         "incrs_gather.py", "incrs_gather",
         dict(m=16, bm=8, n_sections=3, smax=4, section=16),
-        ((16, 3, 4), (16, 3, 4))),
+        ((3, 16, 4), (3, 16, 4))),
     "spgemm_condense": Geometry(
         "spgemm/kernels.py", "spgemm_condense",
         dict(m=16, n=16, bm=8, bn=8, rounds=16, n_rounds=2, rmax_a=3,
              rmax_b=3),
-        ((16, 2, 3), (16, 2, 3), (16, 2, 3), (16, 2, 3))),
+        ((2, 16, 3), (2, 16, 3), (2, 16, 3), (2, 16, 3))),
     "spgemm_merge": Geometry(
         "spgemm/kernels.py", "spgemm_merge",
         dict(m=16, n=16, bm=8, bn=8, n_rounds=2),
@@ -1492,7 +1492,7 @@ def check_config_bounds(variant: str, *, m: int, n: int, bm: int,
     env = dict(m=mp, mp=mp, bm=eff_bm, n=n, bn=bn,
                n_sections=n_sections, smax=smax, section=section,
                k=n_sections * section)
-    ops = ((mp, n_sections, smax), (mp, n_sections, smax),
+    ops = ((n_sections, mp, smax), (n_sections, mp, smax),
            (n_sections * section, n))
     geom = Geometry("incrs_spmm.py", entry, env, ops)
     # This sits on the auto-dispatch hot path (model_pick_variant runs
@@ -1551,8 +1551,8 @@ def check_matched_bounds(stage: str, *, m: int, n: int, bm: int, bn: int,
     else:
         env = dict(m=m, n=n, bm=bm, bn=bn, rounds=rounds,
                    n_rounds=n_rounds, rmax_a=rmax_a, rmax_b=rmax_b)
-        ops = ((m, n_rounds, rmax_a), (m, n_rounds, rmax_a),
-               (n, n_rounds, rmax_b), (n, n_rounds, rmax_b))
+        ops = ((n_rounds, m, rmax_a), (n_rounds, m, rmax_a),
+               (n_rounds, n, rmax_b), (n_rounds, n, rmax_b))
     geom = Geometry(module, entry, env, ops)
     key = None
     if source is None:

@@ -57,10 +57,6 @@ LANE = 128
 # are single-instance.
 PIPELINE_BUFFERS = 2
 
-# Mirror of kernels/incrs_spmm._ONEHOT_BYTES: the one-hot expansion
-# transient is chunked over smax so it never exceeds this.
-ONEHOT_BYTES = 2 * 1024 * 1024
-
 INCRS_VARIANTS = ("expand", "reuse", "pipelined")
 
 # Expected scratch_shapes signature per kernel entry point, derived
@@ -176,12 +172,12 @@ class VmemFootprint:
         return "\n".join(lines)
 
 
-def _onehot_term(bm: int, smax: int, section: int) -> VmemTerm:
-    """Transient of ``_expand_stripe``: the (bm, chunk, section) one-hot
-    slab, chunked over smax to stay under ONEHOT_BYTES."""
-    chunk = min(max(1, smax), max(1, ONEHOT_BYTES // (bm * section * 4)))
-    return VmemTerm("onehot_transient", "transient", (bm, chunk, section),
-                    4, 1, note="chunked expansion slab")
+def _expand_term(rows: int, width: int, name: str = "expand_transient"
+                 ) -> VmemTerm:
+    """Transient of ``_expand_stripe``: the (rows, width) column iota and
+    the stripe carried through its slot loop."""
+    return VmemTerm(name, "transient", (2, rows, width), 4, 1,
+                    note="column iota + loop-carried stripe")
 
 
 # ----------------------------------------------------------------------
@@ -203,33 +199,33 @@ def incrs_footprint(variant: str, *, m: int, n: int, bm: int, bn: int,
     if variant == "expand":
         grid = (mp // bm, np_ // bn, n_sections)
         terms = (
-            VmemTerm("idx_block", "in_spec", (bm, 1, smax), 4, P),
-            VmemTerm("val_block", "in_spec", (bm, 1, smax), 4, P),
+            VmemTerm("idx_block", "in_spec", (1, bm, smax), 4, P),
+            VmemTerm("val_block", "in_spec", (1, bm, smax), 4, P),
             VmemTerm("rhs_block", "in_spec", (section, bn),
                      rhs_dtype_bytes, P),
             VmemTerm("out_tile", "out_spec", (bm, bn), 4, P),
             VmemTerm("acc_scratch", "scratch", (bm, bn), 4, 1),
-            _onehot_term(bm, smax, section),
+            _expand_term(bm, section),
         )
     elif variant == "reuse":
         grid = (mp // bm, n_sections, np_ // bn)
         terms = (
-            VmemTerm("idx_block", "in_spec", (bm, 1, smax), 4, P),
-            VmemTerm("val_block", "in_spec", (bm, 1, smax), 4, P),
+            VmemTerm("idx_block", "in_spec", (1, bm, smax), 4, P),
+            VmemTerm("val_block", "in_spec", (1, bm, smax), 4, P),
             VmemTerm("rhs_block", "in_spec", (section, bn),
                      rhs_dtype_bytes, P),
             VmemTerm("out_tile", "out_spec", (bm, bn), 4, P),
             VmemTerm("stripe_scratch", "scratch", (bm, section), 4, 1),
             VmemTerm("row_panel_accumulator", "scratch", (bm, np_), 4, 1,
                      note="output-stationary (bm, Np) panel"),
-            _onehot_term(bm, smax, section),
+            _expand_term(bm, section),
         )
     else:                              # pipelined
         grid = (mp // bm,)
         terms = (
-            VmemTerm("idx_block", "in_spec", (bm, n_sections, smax), 4, P,
+            VmemTerm("idx_block", "in_spec", (n_sections, bm, smax), 4, P,
                      note="whole row-panel stripes"),
-            VmemTerm("val_block", "in_spec", (bm, n_sections, smax), 4, P,
+            VmemTerm("val_block", "in_spec", (n_sections, bm, smax), 4, P,
                      note="whole row-panel stripes"),
             # RHS stays in HBM (memory_space=ANY): zero VMEM, streamed
             # through the rhs_stream_window below by manual DMA.
@@ -239,7 +235,7 @@ def incrs_footprint(variant: str, *, m: int, n: int, bm: int, bn: int,
                      rhs_dtype_bytes, 1,
                      note="double-buffered manual-DMA window"),
             VmemTerm("stripe_scratch", "scratch", (bm, section), 4, 1),
-            _onehot_term(bm, smax, section),
+            _expand_term(bm, section),
         )
     return VmemFootprint("incrs_spmm", variant, grid, terms)
 
@@ -295,7 +291,7 @@ def matched_footprint(stage: str, *, m: int, n: int, bm: int, bn: int,
     ``spgemm/kernels.py``.
 
     Stages: ``"index_match"`` (fused reference), ``"condense"`` (stripe
-    writer — NO scratch, but two (rows, rmax, R) one-hot transients),
+    writer — NO scratch, but two (rows, R) expansion transients),
     ``"merge"`` (stripe reader with the f32 accumulator scratch).
     """
     if stage not in ("index_match", "condense", "merge"):
@@ -313,14 +309,12 @@ def matched_footprint(stage: str, *, m: int, n: int, bm: int, bn: int,
         )
         return VmemFootprint("spgemm_merge", None, grid, terms)
     operand_terms = (
-        VmemTerm("a_idx_block", "in_spec", (bm, 1, rmax_a), 4, P),
-        VmemTerm("a_val_block", "in_spec", (bm, 1, rmax_a), 4, P),
-        VmemTerm("b_idx_block", "in_spec", (bn, 1, rmax_b), 4, P),
-        VmemTerm("b_val_block", "in_spec", (bn, 1, rmax_b), 4, P),
-        VmemTerm("a_onehot_transient", "transient", (bm, rmax_a, rounds),
-                 4, 1, note="_densify compare tensor"),
-        VmemTerm("b_onehot_transient", "transient", (bn, rmax_b, rounds),
-                 4, 1, note="_densify compare tensor"),
+        VmemTerm("a_idx_block", "in_spec", (1, bm, rmax_a), 4, P),
+        VmemTerm("a_val_block", "in_spec", (1, bm, rmax_a), 4, P),
+        VmemTerm("b_idx_block", "in_spec", (1, bn, rmax_b), 4, P),
+        VmemTerm("b_val_block", "in_spec", (1, bn, rmax_b), 4, P),
+        _expand_term(bm, rounds, "a_expand_transient"),
+        _expand_term(bn, rounds, "b_expand_transient"),
     )
     if stage == "condense":
         terms = operand_terms + (

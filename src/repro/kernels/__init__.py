@@ -5,7 +5,7 @@ Modules:
   bsr_spmm          — block-sparse x dense steered by prefix counters (InCRS idea)
   index_match_spmm  — round-synchronized Alg. 2 port (comparators -> one-hot VPU)
   incrs_gather      — counter-vector-driven column gather / decompression
-  incrs_spmm        — FUSED InCRS SpMM: section-stripe one-hot expansion in
+  incrs_spmm        — FUSED InCRS SpMM: section-stripe expansion in
                       VMEM straight into MXU accumulation; the dense (M, K)
                       intermediate of gather->dense_mm never touches HBM
   flash_attention   — GQA flash attention (online softmax in VMEM scratch,

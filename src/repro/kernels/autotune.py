@@ -35,8 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis import kernel_check, vmem
-from ..core.mesh_sim import (FusedKernelCost, MatchedKernelCost, SpGEMMCost,
-                             fused_spmm_cost, index_match_cost)
+from ..core.mesh_sim import (MXU_MACS, FusedKernelCost, MatchedKernelCost,
+                             SpGEMMCost, fused_spmm_cost, index_match_cost)
 from .incrs_spmm import (incrs_spmm, incrs_spmm_pipelined,
                          incrs_spmm_reuse, _resolve_row_tile)
 
@@ -55,8 +55,26 @@ CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 # fallback gate so the two always agree.
 PANEL_BYTES = vmem.PANEL_BYTES
 
-# Cycles -> wall time for compiled TPU execution (v4-class core clock).
-TPU_CLOCK_HZ = 940e6
+# Cost-model cycles per second on each chip, keyed by
+# ``jax.Device.device_kind``. One model cycle retires one 128x128 MXU's
+# MACs (``mesh_sim.MXU_MACS``), so the rate is the chip's published bf16
+# peak over the FLOPs of such a cycle. v5e: 197 TFLOP/s bf16 (Google Cloud
+# documentation, "TPU v5e"). The model's HBM and VPU terms are still the
+# constants of ``core.mesh_sim`` and await a calibration on the chip.
+TPU_CLOCK_HZ: Dict[str, float] = {
+    "TPU v5 lite": 197e12 / (2 * MXU_MACS),
+}
+
+
+def tpu_clock_hz() -> float:
+    """Cost-model clock of the attached chip; an unknown kind is an
+    error, never a default."""
+    kind = jax.devices()[0].device_kind
+    if kind not in TPU_CLOCK_HZ:
+        raise ValueError(f"no cost-model clock for device kind {kind!r}; "
+                         f"add its published peak to "
+                         f"autotune.TPU_CLOCK_HZ")
+    return TPU_CLOCK_HZ[kind]
 
 # Interpret-mode wall cost is dominated by per-op Python dispatch, not
 # cycles: model it as flat per-grid-step / per-expansion / per-dot costs
@@ -244,7 +262,7 @@ def predict_us(variant: str, m: int, n: int, *, n_sections: int, smax: int,
         return (cost.grid_steps * _I_STEP_US
                 + cost.expansions * _I_EXPAND_US
                 + cost.dots * _I_DOT_US)
-    return cost.cycles / TPU_CLOCK_HZ * 1e6
+    return cost.cycles / tpu_clock_hz() * 1e6
 
 
 def engine_predict_us(cost: MatchedKernelCost, interpret: bool) -> float:
@@ -255,7 +273,7 @@ def engine_predict_us(cost: MatchedKernelCost, interpret: bool) -> float:
         return (cost.grid_steps * _IM_STEP_US
                 + cost.expand_elems * _IM_ELEM_US
                 + cost.interp_copy_bytes * _IM_COPY_US_PER_BYTE)
-    return cost.cycles / TPU_CLOCK_HZ * 1e6
+    return cost.cycles / tpu_clock_hz() * 1e6
 
 
 def predict_matched_us(m: int, n: int, *, rounds: int, n_rounds: int,
@@ -426,7 +444,7 @@ def tune(idx, val, b, *, section: int, interpret: bool,
     """
     global LAST_SWEEP
     t_sweep = time.perf_counter()
-    m, n_sections, smax = idx.shape
+    n_sections, m, smax = idx.shape
     n = b.shape[1]
     key = cache_key(m, n_sections, smax, section, n,
                     backend_name(interpret))

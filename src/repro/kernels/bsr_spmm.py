@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 
 def _kernel(row_of_ref, col_of_ref, values_ref, b_ref, o_ref, acc_ref):
@@ -97,6 +96,6 @@ def bsr_spmm(row_of: jnp.ndarray, col_of: jnp.ndarray, values: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((n_block_rows * bm, n), b.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(row_of, col_of, values, b)

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref):
@@ -56,6 +55,6 @@ def dense_mm(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128, bn: int = 128,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, b)

@@ -5,7 +5,8 @@ matrix O(1)-locatable. On TPU, the consumer of such access is a matmul that
 wants a dense (rows, section) slab in VMEM. This kernel performs the
 decompression: per (row-tile, section) grid cell it scatters the section's
 non-zeros (located on the host via the packed counter-vectors, see
-``ops.prep_sections``) into a dense stripe using a one-hot VPU expansion.
+``ops.prep_sections``) into a dense stripe with the same VPU expansion the
+fused SpMM kernels use (``incrs_spmm._expand_stripe``).
 
 The counter-vectors' role survives intact: the host-side ``prep_sections``
 uses ONLY the 64-bit counter words (prefix + per-block counts) to compute
@@ -22,17 +23,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from .incrs_spmm import _expand_stripe
 
 
 def _kernel(idx_ref, val_ref, o_ref, *, section: int):
-    idx = idx_ref[:, 0, :]                 # (bm, smax) local col in section
-    val = val_ref[:, 0, :]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, section), 2)
-    oh = (idx[..., None] == iota).astype(jnp.float32)
-    o_ref[...] = jnp.einsum(
-        "srk,sr->sk", oh, val.astype(jnp.float32),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    o_ref[...] = _expand_stripe(idx_ref[0], val_ref[0],
+                                section).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -41,10 +37,10 @@ def incrs_gather(idx: jnp.ndarray, val: jnp.ndarray, *, section: int = 256,
                  bm: int = 8, interpret: bool = False) -> jnp.ndarray:
     """Dense[M, n_sections * section] from padded per-section sparse rows.
 
-    idx : (M, n_sections, smax) int32 local column within section, -1 = pad
-    val : (M, n_sections, smax)
+    idx : (n_sections, M, smax) int32 local column within section, -1 = pad
+    val : (n_sections, M, smax)
     """
-    m, n_sections, smax = idx.shape
+    n_sections, m, smax = idx.shape
     if m % bm != 0:
         raise ValueError(f"m={m} must be a multiple of bm={bm}")
     grid = (m // bm, n_sections)
@@ -52,13 +48,13 @@ def incrs_gather(idx: jnp.ndarray, val: jnp.ndarray, *, section: int = 256,
         functools.partial(_kernel, section=section),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1, smax), lambda i, s: (i, s, 0)),
-            pl.BlockSpec((bm, 1, smax), lambda i, s: (i, s, 0)),
+            pl.BlockSpec((1, bm, smax), lambda i, s: (s, i, 0)),
+            pl.BlockSpec((1, bm, smax), lambda i, s: (s, i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, section), lambda i, s: (i, s)),
         out_shape=jax.ShapeDtypeStruct((m, n_sections * section),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(idx, val)

@@ -5,7 +5,7 @@ The two-pass pipeline (``incrs_gather`` -> dense ``(M, K)`` in HBM ->
 designed to avoid. This kernel fuses the two: per ``(row-tile, col-tile,
 section)`` grid step it
 
-  1. one-hot-expands the section's sparse stripe (padded per-(row, section)
+  1. expands the section's sparse stripe (padded per-(section, row)
      ``idx``/``val`` from ``ops.prep_sections``, located purely via the
      packed counter-vectors) into a dense ``(bm, section)`` slab in VMEM, and
   2. immediately contracts that slab against the matching ``(section, bn)``
@@ -41,14 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
-
-# Peak size of the transient one-hot tensor (bm, chunk, section) f32. At
-# high density smax approaches `section`, and an unchunked expansion would
-# be bm*smax*section*4B — 16MB at bm=128/smax=128/section=256, i.e. a whole
-# TPU core's VMEM. Chunking the smax axis bounds it regardless of density.
-_ONEHOT_BYTES = 2 * 1024 * 1024
 
 # TPU f32 sublane granularity: row tiles are kept to multiples of this so
 # padded panels still map onto native (8, 128) vregs.
@@ -72,10 +64,10 @@ def _resolve_row_tile(m: int, bm: int) -> tuple[int, int]:
 def _pad_rows(idx: jnp.ndarray, val: jnp.ndarray,
               padded_m: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Pad the row axis with empty stripes (idx=-1 rows expand to zeros)."""
-    m = idx.shape[0]
+    m = idx.shape[1]
     if padded_m == m:
         return idx, val
-    pad = ((0, padded_m - m), (0, 0), (0, 0))
+    pad = ((0, 0), (0, padded_m - m), (0, 0))
     return (jnp.pad(idx, pad, constant_values=-1),
             jnp.pad(val, pad))
 
@@ -94,18 +86,27 @@ def _check_grid(m: int, n: int, bm: int, bn: int,
 
 
 def _expand_stripe(idx, val, section: int) -> jnp.ndarray:
-    """One-hot-expand one (bm, smax) section stripe to dense (bm, section),
-    chunked over smax so the one-hot transient stays VMEM-sized."""
+    """Expand one (bm, smax) section stripe to dense (bm, section).
+
+    Slot k of every row is pulled out as a (bm, 1) column by a masked lane
+    reduction and scattered with a lane compare against the column iota.
+    Columns within a (row, section) are distinct, so each output element
+    is written by at most one slot and the result is exact. Everything is
+    2-D (no gather, no 3-D one-hot), which is what Mosaic lowers.
+    """
     bm, smax = idx.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, section), 2)
-    chunk = max(1, _ONEHOT_BYTES // (bm * section * 4))
-    stripe = jnp.zeros((bm, section), jnp.float32)
-    for k0 in range(0, smax, chunk):
-        oh = (idx[:, k0:k0 + chunk, None] == iota).astype(jnp.float32)
-        stripe += jnp.einsum(
-            "rks,rk->rs", oh, val[:, k0:k0 + chunk].astype(jnp.float32),
-            preferred_element_type=jnp.float32)
-    return stripe
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bm, section), 1)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (bm, smax), 1)
+    val = val.astype(jnp.float32)
+
+    def slot(k, stripe):
+        at_k = slots == k
+        col_k = jnp.sum(jnp.where(at_k, idx, 0), axis=1, keepdims=True)
+        val_k = jnp.sum(jnp.where(at_k, val, 0.0), axis=1, keepdims=True)
+        return jnp.where(col_k == cols, val_k, stripe)
+
+    return jax.lax.fori_loop(0, smax, slot,
+                             jnp.zeros((bm, section), jnp.float32))
 
 
 def _kernel(idx_ref, val_ref, b_ref, o_ref, acc_ref, *, section: int):
@@ -114,7 +115,7 @@ def _kernel(idx_ref, val_ref, b_ref, o_ref, acc_ref, *, section: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Dense stripe of A for this (row-tile, section) — exists only in VMEM.
-    stripe = _expand_stripe(idx_ref[:, 0, :], val_ref[:, 0, :], section)
+    stripe = _expand_stripe(idx_ref[0], val_ref[0], section)
     acc_ref[...] += jnp.dot(stripe, b_ref[...].astype(jnp.float32),
                             preferred_element_type=jnp.float32)
 
@@ -131,11 +132,11 @@ def incrs_spmm(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
     """C[M, N] = decompress(idx, val) @ B without materializing the left
     operand in HBM.
 
-    idx : (M, n_sections, smax) int32 local column within section, -1 = pad
-    val : (M, n_sections, smax) values
+    idx : (n_sections, M, smax) int32 local column within section, -1 = pad
+    val : (n_sections, M, smax) values
     b   : (n_sections * section, N) dense operand (pre-padded)
     """
-    m, n_sections, smax = idx.shape
+    n_sections, m, smax = idx.shape
     k, n = b.shape
     bm, mp = _resolve_row_tile(m, bm)
     _check_grid(mp, n, bm, bn, k, n_sections, section)
@@ -145,15 +146,15 @@ def incrs_spmm(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
         functools.partial(_kernel, section=section),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1, smax), lambda i, j, s: (i, s, 0)),
-            pl.BlockSpec((bm, 1, smax), lambda i, j, s: (i, s, 0)),
+            pl.BlockSpec((1, bm, smax), lambda i, j, s: (s, i, 0)),
+            pl.BlockSpec((1, bm, smax), lambda i, j, s: (s, i, 0)),
             pl.BlockSpec((section, bn), lambda i, j, s: (s, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(idx, val, b)
     return out[:m] if mp != m else out
@@ -168,7 +169,7 @@ def incrs_spmm(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
 # (the out block is revisited once per section, non-consecutively, so the
 # running sum must live in scratch): SpArch/Sextans-style output-stationary
 # accumulation. The full VMEM footprint (panel + stripe + the idx/val/rhs
-# pipeline blocks + the one-hot transient) is modelled symbolically in
+# pipeline blocks + the expansion transient) is modelled symbolically in
 # ``analysis.vmem.incrs_footprint("reuse", ...)`` — that model, not a
 # hand-kept formula here, is what callers (ops.spmm variant="auto", the
 # autotuner's candidate prefilter) consult to fall back to the baseline
@@ -181,8 +182,7 @@ def _kernel_reuse(idx_ref, val_ref, b_ref, o_ref, stripe_ref, acc_ref, *,
 
     @pl.when(j == 0)
     def _expand():
-        stripe_ref[...] = _expand_stripe(idx_ref[:, 0, :], val_ref[:, 0, :],
-                                         section)
+        stripe_ref[...] = _expand_stripe(idx_ref[0], val_ref[0], section)
 
     contrib = jnp.dot(stripe_ref[...], b_ref[...].astype(jnp.float32),
                       preferred_element_type=jnp.float32)
@@ -210,7 +210,7 @@ def incrs_spmm_reuse(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
     exactly once per row tile (held in VMEM scratch) instead of once per
     (row tile, col tile): n_sections expansions per row tile vs
     n_sections * n_col_tiles."""
-    m, n_sections, smax = idx.shape
+    n_sections, m, smax = idx.shape
     k, n = b.shape
     bm, mp = _resolve_row_tile(m, bm)      # shard-local grid bounds
     _check_grid(mp, n, bm, bn, k, n_sections, section)
@@ -220,8 +220,8 @@ def incrs_spmm_reuse(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
         functools.partial(_kernel_reuse, section=section, bn=bn),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1, smax), lambda i, s, j: (i, s, 0)),
-            pl.BlockSpec((bm, 1, smax), lambda i, s, j: (i, s, 0)),
+            pl.BlockSpec((1, bm, smax), lambda i, s, j: (s, i, 0)),
+            pl.BlockSpec((1, bm, smax), lambda i, s, j: (s, i, 0)),
             pl.BlockSpec((section, bn), lambda i, s, j: (s, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, s, j: (i, j)),
@@ -229,7 +229,7 @@ def incrs_spmm_reuse(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((bm, section), jnp.float32),
                         pltpu.VMEM((bm, n), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(idx, val, b)
     return out[:m] if mp != m else out
@@ -251,7 +251,7 @@ def incrs_spmm_reuse(idx: jnp.ndarray, val: jnp.ndarray, b: jnp.ndarray, *,
 
 def _kernel_pipelined(idx_ref, val_ref, b_hbm, o_ref, b_buf, sem,
                       stripe_ref, *, section: int, bn: int, n_ct: int):
-    n_sections = idx_ref.shape[1]
+    n_sections = idx_ref.shape[0]
     total = n_sections * n_ct
 
     def block_copy(slot, t):
@@ -273,11 +273,7 @@ def _kernel_pipelined(idx_ref, val_ref, b_hbm, o_ref, b_buf, sem,
         # RHS block is (potentially) still in flight.
         @pl.when(j == 0)
         def _expand():
-            idx_s = pl.load(idx_ref, (slice(None), pl.dslice(s, 1),
-                                      slice(None)))
-            val_s = pl.load(val_ref, (slice(None), pl.dslice(s, 1),
-                                      slice(None)))
-            stripe_ref[...] = _expand_stripe(idx_s[:, 0, :], val_s[:, 0, :],
+            stripe_ref[...] = _expand_stripe(idx_ref[s], val_ref[s],
                                              section)
 
         block_copy(t % 2, t).wait()
@@ -307,14 +303,14 @@ def incrs_spmm_pipelined(idx: jnp.ndarray, val: jnp.ndarray,
     """Same contract as ``incrs_spmm``; RHS is double-buffered from HBM.
 
     The per-row-tile VMEM footprint (out panel, stripe, the 2-deep RHS
-    stream window, idx/val pipeline blocks, one-hot transient) is
+    stream window, idx/val pipeline blocks, expansion transient) is
     modelled term-by-term in ``analysis.vmem.incrs_footprint("pipelined",
     ...)``; callers (``ops.spmm``/autotuner) consult that model and fall
     back to the baseline order when the panel would not fit. The dot
     shape and section accumulation order match the other variants
     exactly, so outputs are bitwise identical at equal (bm, bn).
     """
-    m, n_sections, smax = idx.shape
+    n_sections, m, smax = idx.shape
     k, n = b.shape
     bm, mp = _resolve_row_tile(m, bm)
     _check_grid(mp, n, bm, bn, k, n_sections, section)
@@ -325,9 +321,9 @@ def incrs_spmm_pipelined(idx: jnp.ndarray, val: jnp.ndarray,
                           n_ct=n_ct),
         grid=(mp // bm,),
         in_specs=[
-            pl.BlockSpec((bm, n_sections, smax), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bm, n_sections, smax), lambda i: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((n_sections, bm, smax), lambda i: (0, i, 0)),
+            pl.BlockSpec((n_sections, bm, smax), lambda i: (0, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
@@ -335,7 +331,7 @@ def incrs_spmm_pipelined(idx: jnp.ndarray, val: jnp.ndarray,
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.VMEM((bm, section), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(idx, val, b)
     return out[:m] if mp != m else out

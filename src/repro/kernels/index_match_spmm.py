@@ -17,8 +17,8 @@ Alg. 2 (depth R) become the R-wide stripes themselves; the round barrier is
 the grid step.
 
 Inputs are padded per-round sparse rows from ``ops.prep_rounds``:
-  idx (M, n_rounds, rmax) int32 local index in [0, R), -1 = padding
-  val (M, n_rounds, rmax) values
+  idx (n_rounds, M, rmax) int32 local index in [0, R), -1 = padding
+  val (n_rounds, M, rmax) values
 Since at most R non-zeros fit in a round window, rmax <= R.
 
 Computes C = A @ B.T (both operands row-stored — the paper's A x A^T
@@ -33,16 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
-
-def _densify(idx, val, rounds: int):
-    """(rows, rmax) sparse -> (rows, R) dense stripe via one-hot matmul."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rounds), 2)
-    oh = (idx[..., None] == iota).astype(jnp.float32)     # (rows, rmax, R)
-    return jnp.einsum("srk,sr->sk", oh,
-                      val.astype(jnp.float32),
-                      preferred_element_type=jnp.float32)
+from .incrs_spmm import _expand_stripe
 
 
 def _kernel(a_idx_ref, a_val_ref, b_idx_ref, b_val_ref, o_ref, acc_ref, *,
@@ -51,8 +42,8 @@ def _kernel(a_idx_ref, a_val_ref, b_idx_ref, b_val_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    da = _densify(a_idx_ref[:, 0, :], a_val_ref[:, 0, :], rounds)  # (bm, R)
-    db = _densify(b_idx_ref[:, 0, :], b_val_ref[:, 0, :], rounds)  # (bn, R)
+    da = _expand_stripe(a_idx_ref[0], a_val_ref[0], rounds)  # (bm, R)
+    db = _expand_stripe(b_idx_ref[0], b_val_ref[0], rounds)  # (bn, R)
     acc_ref[...] += jax.lax.dot_general(
         da, db, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -81,8 +72,8 @@ def index_match_spmm(a_idx: jnp.ndarray, a_val: jnp.ndarray,
     """
     if out_dtype is None:
         out_dtype = jnp.result_type(a_val.dtype, b_val.dtype)
-    m, n_rounds, rmax_a = a_idx.shape
-    n, n_rounds_b, rmax_b = b_idx.shape
+    n_rounds, m, rmax_a = a_idx.shape
+    n_rounds_b, n, rmax_b = b_idx.shape
     if n_rounds != n_rounds_b:
         raise ValueError(
             f"operand round counts differ: {n_rounds} vs {n_rounds_b}")
@@ -96,15 +87,15 @@ def index_match_spmm(a_idx: jnp.ndarray, a_val: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1, rmax_a), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((bm, 1, rmax_a), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((bn, 1, rmax_b), lambda i, j, t: (j, t, 0)),
-            pl.BlockSpec((bn, 1, rmax_b), lambda i, j, t: (j, t, 0)),
+            pl.BlockSpec((1, bm, rmax_a), lambda i, j, t: (t, i, 0)),
+            pl.BlockSpec((1, bm, rmax_a), lambda i, j, t: (t, i, 0)),
+            pl.BlockSpec((1, bn, rmax_b), lambda i, j, t: (t, j, 0)),
+            pl.BlockSpec((1, bn, rmax_b), lambda i, j, t: (t, j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a_idx, a_val, b_idx, b_val)

@@ -9,9 +9,11 @@ The historical per-format entry points (``incrs_spmm``, ``bsr_matmul``,
 ``index_match_matmul``, ``incrs_spmm_sharded``) remain as one-release
 deprecation shims over the same implementations.
 
-On CPU (this container) the kernels run in Pallas ``interpret`` mode; on a
-real TPU backend they compile to Mosaic. ``INTERPRET`` is resolved once from
-the backend.
+On a TPU backend the kernels compile to Mosaic. Without one (the CPU test
+backend) they run in Pallas ``interpret`` mode. ``INTERPRET`` is resolved
+once from the backend, and ``resolve_interpret`` refuses interpret mode on a
+TPU backend, so no kernel behind this module runs in the interpreter on the
+chip.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .._deprecation import deprecated
@@ -31,7 +34,6 @@ from ..core.crs import CRS
 from ..core.incrs import InCRS
 from ..core import mesh_sim as _mesh_sim
 from . import ref
-from ._compat import SHARD_MAP_KW, shard_map
 from .bsr_spmm import bsr_spmm as _bsr_spmm_kernel
 from .flash_attention import flash_attention as _flash_kernel
 from .dense_mm import dense_mm as _dense_mm_kernel
@@ -46,11 +48,22 @@ from ..analysis import kernel_check as _kernel_check
 INTERPRET = jax.default_backend() != "tpu"
 
 
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` -> ``INTERPRET``. Interpret mode is the CPU test switch
+    only: asking for it on a TPU backend is an error, never a fallback."""
+    if interpret is None:
+        return INTERPRET
+    if interpret and not INTERPRET:
+        raise ValueError("interpret mode was requested on a TPU backend; "
+                         "kernels here always compile to Mosaic")
+    return bool(interpret)
+
+
 # ----------------------------------------------------------------------
 def dense_mm(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
              interpret: bool | None = None):
     """Tiled dense matmul; pads every dim up to its tile size."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     m, k = a.shape
     _, n = b.shape
     mp, kp, np_ = -(-m // bm) * bm, -(-k // bk) * bk, -(-n // bn) * bn
@@ -104,7 +117,7 @@ def prep_bsr(bsr: BSR) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
 
 def _spmm_bsr(bsr: BSR, b, *, bn: int = 128, interpret: bool | None = None):
     """C = BSR(A) @ B through the prefix-counter-steered Pallas kernel."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     row_of, col_of, values = prep_bsr(bsr)
     k, n = b.shape
     if k != bsr.shape[1]:
@@ -122,7 +135,7 @@ def bsr_matmul_arrays(row_of, col_of, values, b, *, n_block_rows: int,
                       bn: int = 128, interpret: bool | None = None):
     """Same as ``bsr_matmul`` but from pre-prepared (traced) arrays —
     the entry point used by ``sparse.SparseLinear`` inside jit."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _bsr_spmm_kernel(row_of, col_of, values, b,
                             n_block_rows=n_block_rows, bn=bn,
                             interpret=interpret)
@@ -132,7 +145,9 @@ def bsr_matmul_arrays(row_of, col_of, values, b, *, n_block_rows: int,
 def prep_rounds(crs: CRS, rounds: int, rmax: int | None = None,
                 pad_rows_to: int = 128, on_overflow: str = "raise",
                 dtype=np.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """CRS -> padded per-round (idx, val); idx local in [0, R), -1 = pad.
+    """CRS -> padded per-round (idx, val) of shape (n_rounds, Mp, rmax);
+    idx local in [0, R), -1 = pad. Round-major, so each kernel block
+    ``(1, rows, rmax)`` is a native (sublane, lane) tile.
 
     Rows are padded up to a multiple of ``pad_rows_to``; at most R non-zeros
     fit in one round window, so rmax <= R always holds. ``dtype`` sets the
@@ -168,8 +183,8 @@ def prep_rounds(crs: CRS, rounds: int, rmax: int | None = None,
             f"{int((counts > rmax).sum())} overfull (row, round) windows "
             f"(densest holds {rmax_true})", stacklevel=2)
     mp = -(-m // pad_rows_to) * pad_rows_to
-    idx = np.full((mp, n_rounds, rmax), -1, dtype=np.int32)
-    val = np.zeros((mp, n_rounds, rmax), dtype=dtype)
+    idx = np.full((n_rounds, mp, rmax), -1, dtype=np.int32)
+    val = np.zeros((n_rounds, mp, rmax), dtype=dtype)
     if crs.nnz:
         # Non-zeros are sorted by (row, col), hence by (row, round): each
         # (row, round) group is one contiguous run. Slot-within-round =
@@ -183,11 +198,11 @@ def prep_rounds(crs: CRS, rounds: int, rmax: int | None = None,
         if rmax < rmax_true:
             sel = slot < rmax
             row_of, r, slot = row_of[sel], r[sel], slot[sel]
-            idx[row_of, r, slot] = crs.col_idx[sel] % rounds
-            val[row_of, r, slot] = crs.values[sel]
+            idx[r, row_of, slot] = crs.col_idx[sel] % rounds
+            val[r, row_of, slot] = crs.values[sel]
         else:
-            idx[row_of, r, slot] = crs.col_idx % rounds
-            val[row_of, r, slot] = crs.values
+            idx[r, row_of, slot] = crs.col_idx % rounds
+            val[r, row_of, slot] = crs.values
     return jnp.asarray(idx), jnp.asarray(val)
 
 
@@ -199,7 +214,7 @@ def index_match_prepped(ai, av, bi, bv, *, rounds: int = 128,
     a common rmax and runs the kernel. Returns the PADDED output — callers
     trim to the real (M, N). The plan–execute API uses this to prep the
     fixed sparse operand once and stream right-hand sides."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     rmax = max(ai.shape[2], bi.shape[2])
     ai = jnp.pad(ai, ((0, 0), (0, 0), (0, rmax - ai.shape[2])),
                  constant_values=-1)
@@ -236,7 +251,7 @@ def _spmm_index_match(a: CRS, bt: CRS, *, rounds: int | None = None,
     (paper Alg. 2 on the MXU). Returns C[:M, :N] unpadded. ``None``
     tile/round params resolve from the autotuner's matched-family cache
     (``autotune.tune_index_match``) before falling back to 128."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     if a.shape[1] != bt.shape[1]:
         raise ValueError(f"inner dims disagree: A is {a.shape}, "
                          f"Bt is {bt.shape} (expected equal col counts)")
@@ -291,7 +306,7 @@ def _spmm_spgemm(a: CRS, b, *, rounds: int | None = None,
     if variant not in _SPGEMM_VARIANTS:
         raise ValueError(f"variant must be one of {_SPGEMM_VARIANTS}, "
                          f"got {variant!r}")
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     bt = b.crs if isinstance(b, InCRS) else b
     if a.shape[1] != bt.shape[1]:
         raise ValueError(f"inner dims disagree: A is {a.shape}, "
@@ -319,10 +334,14 @@ def _spmm_spgemm(a: CRS, b, *, rounds: int | None = None,
 # ----------------------------------------------------------------------
 def prep_sections(incrs: InCRS, pad_rows_to: int = 8
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """InCRS -> padded per-(row, section) (idx, val) using ONLY the packed
+    """InCRS -> padded per-(section, row) (idx, val) using ONLY the packed
     counter-vectors for location (the paper's access path): the prefix word
     gives each section's start offset inside the row, the block counts give
     its length. No row scan ever happens.
+
+    Both arrays are section-major, ``(n_sections, Mp, smax)``: a kernel
+    block ``(1, bm, smax)`` is then one section stripe of ``bm`` rows whose
+    last two dims map onto native (sublane, lane) tiles.
 
     Fully vectorized: one batched ``_unpack64`` over the whole counter array
     yields every (start, count) span at once; the gather + scatter runs over
@@ -336,8 +355,8 @@ def prep_sections(incrs: InCRS, pad_rows_to: int = 8
     starts = crs.row_ptr[:m, None] + prefix            # (m, n_sections)
     smax = max(1, int(cnt.max(initial=0)))
     mp = -(-m // pad_rows_to) * pad_rows_to
-    idx = np.full((mp, n_sections, smax), -1, dtype=np.int32)
-    val = np.zeros((mp, n_sections, smax), dtype=np.float32)
+    idx = np.full((n_sections, mp, smax), -1, dtype=np.int32)
+    val = np.zeros((n_sections, mp, smax), dtype=np.float32)
     total = int(cnt.sum())
     if total:
         flat_cnt = cnt.reshape(-1)
@@ -349,8 +368,8 @@ def prep_sections(incrs: InCRS, pad_rows_to: int = 8
         grid_i, grid_s = np.indices((m, n_sections))
         rows = np.repeat(grid_i.reshape(-1), flat_cnt)
         secs = np.repeat(grid_s.reshape(-1), flat_cnt)
-        idx[rows, secs, slot] = crs.col_idx[src] - secs * incrs.section
-        val[rows, secs, slot] = crs.values[src]
+        idx[secs, rows, slot] = crs.col_idx[src] - secs * incrs.section
+        val[secs, rows, slot] = crs.values[src]
     return jnp.asarray(idx), jnp.asarray(val)
 
 
@@ -365,18 +384,22 @@ class PreparedOperand:
     the same operand reuses the arrays. Produced by ``prepare_incrs`` which
     memoizes per live InCRS object.
     """
-    idx: jnp.ndarray              # (Mp, n_sections, smax) int32, -1 = pad
-    val: jnp.ndarray              # (Mp, n_sections, smax) f32
+    idx: jnp.ndarray              # (n_sections, Mp, smax) int32, -1 = pad
+    val: jnp.ndarray              # (n_sections, Mp, smax) f32
     shape: Tuple[int, int]        # original (M, K) of the sparse operand
     section: int
 
     @property
     def n_sections(self) -> int:
-        return self.idx.shape[1]
+        return self.idx.shape[0]
 
     @property
     def padded_rows(self) -> int:
-        return self.idx.shape[0]
+        return self.idx.shape[1]
+
+    @property
+    def smax(self) -> int:
+        return self.idx.shape[2]
 
 
 # id() can be recycled after an object dies — each cache entry carries a
@@ -513,8 +536,8 @@ class ShardedPreparedOperand:
     ``[s * rows_per_shard, (s + 1) * rows_per_shard)`` (the tail shard may
     be partially empty) and ``idx``/``val`` carry a ``NamedSharding`` over
     ``axes`` so no device ever materializes another shard's stripes."""
-    idx: jnp.ndarray              # (n_shards, Rp, n_sections, smax) int32
-    val: jnp.ndarray              # (n_shards, Rp, n_sections, smax) f32
+    idx: jnp.ndarray              # (n_shards, n_sections, Rp, smax) int32
+    val: jnp.ndarray              # (n_shards, n_sections, Rp, smax) f32
     shape: Tuple[int, int]        # global (M, K) of the sparse operand
     section: int
     rows_per_shard: int           # real output rows owned by each shard
@@ -527,11 +550,11 @@ class ShardedPreparedOperand:
 
     @property
     def n_sections(self) -> int:
-        return self.idx.shape[2]
+        return self.idx.shape[1]
 
     @property
     def padded_rows(self) -> int:
-        return self.idx.shape[1]
+        return self.idx.shape[2]
 
 
 def prepare_incrs_sharded(incrs: InCRS, mesh: Mesh, *, axis=None,
@@ -560,18 +583,18 @@ def prepare_incrs_sharded(incrs: InCRS, mesh: Mesh, *, axis=None,
     axes, n_shards = shard_axes(mesh, axis)
     m, _ = incrs.shape
     gi, gv = prep_sections(incrs, pad_rows_to=1)
-    gi, gv = np.asarray(gi), np.asarray(gv)            # (m, Si, smax)
+    gi, gv = np.asarray(gi), np.asarray(gv)            # (Si, m, smax)
     rows_per_shard = -(-m // n_shards)
     rp = -(-rows_per_shard // pad_rows_to) * pad_rows_to
-    _, si, smax = gi.shape
-    idx = np.full((n_shards, rp, si, smax), -1, dtype=np.int32)
-    val = np.zeros((n_shards, rp, si, smax), dtype=np.float32)
+    si, _, smax = gi.shape
+    idx = np.full((n_shards, si, rp, smax), -1, dtype=np.int32)
+    val = np.zeros((n_shards, si, rp, smax), dtype=np.float32)
     for s in range(n_shards):
         lo = s * rows_per_shard
         hi = min(m, lo + rows_per_shard)
         if hi > lo:
-            idx[s, :hi - lo] = gi[lo:hi]
-            val[s, :hi - lo] = gv[lo:hi]
+            idx[s, :, :hi - lo] = gi[:, lo:hi]
+            val[s, :, :hi - lo] = gv[:, lo:hi]
     sharding = NamedSharding(mesh, P(axes))
     return ShardedPreparedOperand(
         jax.device_put(jnp.asarray(idx), sharding),
@@ -619,7 +642,7 @@ def _spmm_incrs_sharded(a: InCRS | ShardedPreparedOperand, b, *,
 
     spec0 = P(prep.axes)
     y = shard_map(local, mesh=prep.mesh, in_specs=(spec0, spec0, P()),
-                  out_specs=P(prep.axes), **SHARD_MAP_KW)(
+                  out_specs=P(prep.axes), check_vma=False)(
         prep.idx, prep.val, jnp.asarray(b))
     return y[:m]
 
@@ -641,7 +664,7 @@ _INCRS_KERNELS = {"expand": _incrs_spmm_kernel,
 def _spmm_incrs(a: InCRS | PreparedOperand, b, *, bm: int = 128,
                 bn: int | None = None, variant: str = "auto",
                 interpret: bool | None = None):
-    """C = A @ B fused: InCRS section stripes are one-hot-expanded in VMEM
+    """C = A @ B fused: InCRS section stripes are expanded in VMEM
     and contracted on the MXU in the same grid step — the dense (M, K)
     intermediate of ``incrs_to_dense -> dense_mm`` never touches HBM.
 
@@ -665,7 +688,7 @@ def _spmm_incrs(a: InCRS | PreparedOperand, b, *, bm: int = 128,
         raise ValueError(f"variant must be 'auto', 'expand', 'reuse' or "
                          f"'pipelined', got {variant!r}")
     explicit_variant = variant != "auto"
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     prep = a if isinstance(a, PreparedOperand) else \
         prepare_incrs(a, pad_rows_to=bm)
     m, k = prep.shape
@@ -675,7 +698,7 @@ def _spmm_incrs(a: InCRS | PreparedOperand, b, *, bm: int = 128,
                          f"B is {b.shape}")
     if variant == "auto":
         tuned = _autotune.lookup(_autotune.cache_key(
-            prep.padded_rows, prep.n_sections, prep.idx.shape[2],
+            prep.padded_rows, prep.n_sections, prep.smax,
             prep.section, n, _autotune.backend_name(interpret)))
         if tuned is not None and bn is None:
             variant, bm, bn = tuned.variant, tuned.bm, tuned.bn
@@ -691,7 +714,7 @@ def _spmm_incrs(a: InCRS | PreparedOperand, b, *, bm: int = 128,
     if variant == "auto":
         variant = _autotune.model_pick_variant(
             prep.padded_rows, np_, n_sections=prep.n_sections,
-            smax=prep.idx.shape[2], section=prep.section, bm=bm, bn=bn,
+            smax=prep.smax, section=prep.section, bm=bm, bn=bn,
             interpret=interpret)
     elif explicit_variant:
         # An explicitly requested variant may ignore the panel working-
@@ -700,7 +723,7 @@ def _spmm_incrs(a: InCRS | PreparedOperand, b, *, bm: int = 128,
         # the violated term) instead of OOMing on hardware.
         _kernel_check.require_feasible(
             variant, m=prep.padded_rows, n=np_, bm=bm, bn=bn,
-            n_sections=prep.n_sections, smax=prep.idx.shape[2],
+            n_sections=prep.n_sections, smax=prep.smax,
             section=prep.section,
             rules=(_kernel_check.RULE_VMEM,),
             context=f"spmm variant={variant!r}")
@@ -717,7 +740,7 @@ def incrs_to_dense(incrs: InCRS, *, bm: int = 8,
     baseline path; kept for tests/benchmarks and ad-hoc densification).
     Prep is memoized per live object — see ``prepare_incrs`` for the
     immutability contract."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     prep = prepare_incrs(incrs, pad_rows_to=bm)
     out = _incrs_gather_kernel(prep.idx, prep.val, section=incrs.section,
                                bm=bm, interpret=interpret)
@@ -810,7 +833,7 @@ def flash_mha(q, k, v, *, window=None, soft_cap=None, bq: int = 128,
     q: (B, Sq, KV, G, hd); k/v: (B, Sk, KV, hd). Causal over absolute
     positions 0..S-1 (prefill/train layout). Returns (B, Sq, KV, G, hd).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, sq, kv, g, hd = q.shape
     _, sk, _, _ = k.shape
     sqp = -(-sq // bq) * bq
@@ -829,8 +852,8 @@ def flash_mha(q, k, v, *, window=None, soft_cap=None, bq: int = 128,
 
 
 __all__ = [
-    "INTERPRET", "spmm", "dense_mm", "bsr_kernel_meta", "prep_bsr",
-    "bsr_matmul_arrays",
+    "INTERPRET", "resolve_interpret", "spmm", "dense_mm", "bsr_kernel_meta",
+    "prep_bsr", "bsr_matmul_arrays",
     "prep_rounds", "index_match_prepped", "prep_sections", "PreparedOperand",
     "prepare_incrs", "invalidate_prepared", "incrs_to_dense",
     "prepare_versioned", "invalidate_pattern",

@@ -39,15 +39,15 @@ def bsr_spmm(values, col_idx, row_ptr, shape, block, b) -> jnp.ndarray:
 def round_densify(idx, val, n_cols: int, rounds: int) -> jnp.ndarray:
     """Densify padded per-round sparse rows.
 
-    idx : (M, n_rounds, rmax) int32 — LOCAL index in [0, rounds), -1 = pad
-    val : (M, n_rounds, rmax)
+    idx : (n_rounds, M, rmax) int32 — LOCAL index in [0, rounds), -1 = pad
+    val : (n_rounds, M, rmax)
     Returns dense (M, n_rounds * rounds)[:, :n_cols].
     """
-    m, n_rounds, rmax = idx.shape
+    n_rounds, m, rmax = idx.shape
     iota = jnp.arange(rounds, dtype=jnp.int32)
     oh = (idx[..., None] == iota) & (idx[..., None] >= 0)
     dense = jnp.sum(oh * val[..., None].astype(jnp.float32), axis=2)
-    return dense.reshape(m, n_rounds * rounds)[:, :n_cols]
+    return dense.transpose(1, 0, 2).reshape(m, n_rounds * rounds)[:, :n_cols]
 
 
 def index_match_spmm(a_idx, a_val, b_idx, b_val, n_cols: int,
@@ -60,6 +60,6 @@ def index_match_spmm(a_idx, a_val, b_idx, b_val, n_cols: int,
 
 
 def incrs_decompress(idx, val, n_cols: int, section: int) -> jnp.ndarray:
-    """Densify padded per-(row, section) sparse data (local column index
+    """Densify padded per-(section, row) sparse data (local column index
     within the section, -1 = pad) — oracle for the InCRS gather kernel."""
     return round_densify(idx, val, n_cols, section)

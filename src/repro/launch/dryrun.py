@@ -48,12 +48,9 @@ _GROUPS_RE = re.compile(r"replica_groups=(?:\{\{([0-9,]+)\}|\[(\d+),(\d+)\])")
 
 
 def cost_dict(compiled) -> Dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a one-element list of dicts, newer ones a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``: a dict, empty when XLA reports no
+    costs."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_bytes(m) -> int:

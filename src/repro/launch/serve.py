@@ -145,6 +145,8 @@ def main(argv=None):
     ap.add_argument("--spmm-density", type=float, default=0.03)
     ap.add_argument("--spmm-batch-cols", type=int, default=64)
     args = ap.parse_args(argv)
+    from .compile_cache import enable
+    enable()
     if args.spmm:
         return _main_spmm(args)
 

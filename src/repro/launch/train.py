@@ -44,6 +44,8 @@ def main(argv=None):
     ap.add_argument("--prune-warmup-frac", type=float, default=0.1)
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable
+    enable()
     import jax
     import jax.numpy as jnp
 

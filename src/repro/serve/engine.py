@@ -293,13 +293,13 @@ class SpMMEngine:
 
     def _seed_cost_model(self) -> _sched.WaveCostModel:
         """Seed the packer's µs/col estimate from measurements this repo
-        already persists: the autotune disk cache for this operand's exact
-        prepared geometry, else the committed bench record, else unseeded
-        (the first retired wave provides the estimate)."""
+        already persists for this engine's backend: the autotune disk
+        cache for this operand's exact prepared geometry, else the
+        committed bench record if it was measured on the same backend,
+        else unseeded (the first retired wave provides the estimate)."""
         from ..kernels import autotune
         backend = autotune.backend_name(
-            self._ops.INTERPRET if self.interpret is None
-            else self.interpret)
+            self._ops.resolve_interpret(self.interpret))
         geom = self._operand_geometry()
         if geom is None:
             return _sched.seed_cost_model(backend=backend,
@@ -320,14 +320,14 @@ class SpMMEngine:
             if arrs is None:
                 return None
             idx, section = arrs
-            return (int(idx.shape[0]), int(idx.shape[1]),
+            return (int(idx.shape[1]), int(idx.shape[0]),
                     int(idx.shape[2]), int(section))
         idx = getattr(prep, "idx", None)
         if idx is None:
             return None
         if idx.ndim == 4:                  # sharded: per-device panel
             idx = idx[0]
-        return (int(idx.shape[0]), int(idx.shape[1]), int(idx.shape[2]),
+        return (int(idx.shape[1]), int(idx.shape[0]), int(idx.shape[2]),
                 int(prep.section))
 
     def _build_operand(self, a, mesh, shard_axis):
@@ -404,8 +404,8 @@ class SpMMEngine:
         tiles = -(-np128 // 512)
         bn = -(-np128 // (tiles * 128)) * 128
         kernel_check.require_feasible(
-            self.variant, m=idx.shape[0], n=self.max_wave_cols, bm=128,
-            bn=bn, n_sections=idx.shape[1], smax=idx.shape[2],
+            self.variant, m=idx.shape[1], n=self.max_wave_cols, bm=128,
+            bn=bn, n_sections=idx.shape[0], smax=idx.shape[2],
             section=prep.section, rules=(kernel_check.RULE_VMEM,),
             context=f"engine variant={self.variant!r} at "
                     f"max_wave_cols={self.max_wave_cols}")

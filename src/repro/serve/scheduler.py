@@ -145,15 +145,22 @@ def seed_from_autotune(padded_rows: int, n_sections: int, smax: int,
                          source=f"autotune[{len(pairs)} pts]")
 
 
-def seed_from_bench(path: str) -> WaveCostModel:
+def seed_from_bench(path: str, backend: str) -> WaveCostModel:
     """Seed a cost model from a committed ``BENCH_kernels.json``: fused
     InCRS rows record their measured µs and RHS width (``cols=N`` in the
     ``derived`` field) — the cheapest µs/col across them is a usable
-    machine-level prior even when the operand geometry differs."""
+    machine-level prior even when the operand geometry differs. A record
+    measured on another ``backend`` (e.g. the CPU interpreter, for a TPU
+    engine) seeds nothing."""
     try:
         with open(path) as f:
             record = json.load(f)
     except (OSError, ValueError):
+        return WaveCostModel()
+    # The record's backend, named as autotune.backend_name names it.
+    measured_on = "interpret" if record.get("interpret") \
+        else record.get("backend")
+    if measured_on != backend:
         return WaveCostModel()
     best: Optional[float] = None
     for row in record.get("rows", []):
@@ -180,16 +187,17 @@ def seed_cost_model(padded_rows: Optional[int] = None,
                     section: Optional[int] = None,
                     backend: str = "interpret",
                     bench_path: Optional[str] = None) -> WaveCostModel:
-    """Best available offline seed: exact-geometry autotune measurements
-    first, the bench record as the machine-level fallback, unseeded last
-    (the first retired wave then provides the estimate)."""
+    """Best available offline seed for ``backend``: exact-geometry
+    autotune measurements first, a bench record measured on the same
+    backend next, unseeded last (the first retired wave then provides the
+    estimate)."""
     if None not in (padded_rows, n_sections, smax, section):
         model = seed_from_autotune(padded_rows, n_sections, smax, section,
                                    backend)
         if model.us_per_col is not None:
             return model
     if bench_path is not None:
-        model = seed_from_bench(bench_path)
+        model = seed_from_bench(bench_path, backend)
         if model.us_per_col is not None:
             return model
     return WaveCostModel()

@@ -213,7 +213,7 @@ register_family(DenseLinearParams, FamilyOps(
 # prep. No trainable layer — plan–execute only.
 @dataclasses.dataclass(eq=False)
 class CRSPlanMeta:
-    ai: jnp.ndarray           # (Mp, n_rounds, rmax) int32 round indices
+    ai: jnp.ndarray           # (n_rounds, Mp, rmax) int32 round indices
     scatter: jnp.ndarray      # (nnz,) flat slots into the val array, in
     #                           A's row-major non-zero order
     shape: Tuple[int, int]    # (M, K) of A
@@ -247,9 +247,9 @@ def _crs_plan_meta(pat: SparsityPattern, rounds: int,
     m, k = mask_a.shape
     crs0 = CRS.from_mask(np.zeros((m, k), np.float32), mask_a)
     ai, _ = ops.prep_rounds(crs0, rounds, pad_rows_to=128)
-    n_rounds, rmax = ai.shape[1], ai.shape[2]
+    n_rounds, mp, rmax = ai.shape
     # Replicate prep_rounds' slot arithmetic to map each non-zero (in CRS
-    # row-major order) to its flat (row, round, slot) cell.
+    # row-major order) to its flat (round, row, slot) cell.
     if crs0.nnz:
         row_of = np.repeat(np.arange(m),
                            np.diff(crs0.row_ptr).astype(np.int64))
@@ -260,7 +260,7 @@ def _crs_plan_meta(pat: SparsityPattern, rounds: int,
                                       np.cumsum(counts.reshape(-1))[:-1]])
         slot = np.arange(crs0.nnz, dtype=np.int64) \
             - group_start[row_of * n_rounds + r]
-        flat = (row_of * n_rounds + r) * rmax + slot
+        flat = (r * mp + row_of) * rmax + slot
     else:
         flat = np.zeros((0,), np.int64)
     return CRSPlanMeta(ai, jnp.asarray(flat, jnp.int32), (m, k), rounds,
@@ -544,9 +544,9 @@ class MatmulPlan:
         if arrs is None:
             return None
         idx, section = arrs
-        interpret = ops.INTERPRET if interpret is None else interpret
+        interpret = ops.resolve_interpret(interpret)
         return _autotune.lookup(_autotune.cache_key(
-            idx.shape[0], idx.shape[1], idx.shape[2], section, n_cols,
+            idx.shape[1], idx.shape[0], idx.shape[2], section, n_cols,
             _autotune.backend_name(interpret)))
 
     def tune(self, n_cols: int, *, interpret: Optional[bool] = None,
@@ -560,10 +560,10 @@ class MatmulPlan:
             raise ValueError(f"format {self.spec.format!r} has no tunable "
                              f"fused kernel")
         idx, section = arrs
-        interpret = ops.INTERPRET if interpret is None else interpret
+        interpret = ops.resolve_interpret(interpret)
         cfg = _autotune.tune(
             idx, jnp.zeros(idx.shape, jnp.float32),
-            jnp.zeros((idx.shape[1] * section, n_cols), jnp.float32),
+            jnp.zeros((idx.shape[0] * section, n_cols), jnp.float32),
             section=section, interpret=interpret, reps=reps,
             persist=persist)
         return dataclasses.replace(self, tuned=cfg)
@@ -584,8 +584,8 @@ class MatmulPlan:
             return
         idx, section = arrs
         _kernel_check.require_feasible(
-            cfg.variant, m=idx.shape[0], n=int(n_cols), bm=cfg.bm,
-            bn=cfg.bn, n_sections=idx.shape[1], smax=idx.shape[2],
+            cfg.variant, m=idx.shape[1], n=int(n_cols), bm=cfg.bm,
+            bn=cfg.bn, n_sections=idx.shape[0], smax=idx.shape[2],
             section=section, rules=_kernel_check.LAUNCH_RULES,
             context=f"plan tuned config ({cfg.variant}, bm={cfg.bm}, "
                     f"bn={cfg.bn})")

@@ -27,13 +27,13 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .._deprecation import deprecated
 from ..core.bsr import BSR, magnitude_block_mask
 from ..core.crs import CRS
 from ..kernels import ops
-from ..kernels._compat import SHARD_MAP_KW, shard_map
 from .pattern import (FamilyOps, SparsityPattern, expand_block_mask,
                       magnitude_mask, register_family)
 
@@ -271,9 +271,9 @@ class InCRSLinearMeta:
     as a ``custom_vjp`` nondiff argument, where identity semantics keep jit
     caches stable (array-valued fields would make generated __eq__ raise).
     """
-    fwd_idx: jnp.ndarray      # (Op, Si, smax) int32 — W^T stripes, -1 pad
-    bwd_idx: jnp.ndarray      # (Ip, So, smax_t) int32 — W stripes, -1 pad
-    t_gather: jnp.ndarray     # (Ip*So*smax_t,) int32 — bwd slot -> flat fwd
+    fwd_idx: jnp.ndarray      # (Si, Op, smax) int32 — W^T stripes, -1 pad
+    bwd_idx: jnp.ndarray      # (So, Ip, smax_t) int32 — W stripes, -1 pad
+    t_gather: jnp.ndarray     # (So*Ip*smax_t,) int32 — bwd slot -> flat fwd
     #                           slot (the one-past-the-end slot reads 0.0)
     d_in: int
     d_out: int
@@ -287,7 +287,7 @@ class InCRSLinearMeta:
 
 @dataclasses.dataclass
 class InCRSLinearParams:
-    values: jnp.ndarray       # (Op, Si, smax) f32 — the trainable leaf
+    values: jnp.ndarray       # (Si, Op, smax) f32 — the trainable leaf
     meta: InCRSLinearMeta
 
     @property
@@ -327,18 +327,18 @@ def _transpose_gather(fwd_idx: np.ndarray, bwd_idx: np.ndarray,
     """Map every bwd stripe slot to the flat fwd slot holding the same
     non-zero (pad slots -> the extra zero slot at index fwd_idx.size).
 
-    Keys are the global (out, in) coordinates: fwd slot (r, s, k) holds
-    W^T[r, idx + s*section]; bwd slot (r', s', k') holds W[r', idx' +
+    Keys are the global (out, in) coordinates: fwd slot (s, r, k) holds
+    W^T[r, idx + s*section]; bwd slot (s', r', k') holds W[r', idx' +
     s'*section] = W^T[idx' + s'*section, r'].
     """
-    r_f, s_f, _ = np.indices(fwd_idx.shape)
+    s_f, r_f, _ = np.indices(fwd_idx.shape)
     fmask = fwd_idx >= 0
     fkey = (r_f[fmask].astype(np.int64) * d_in
             + fwd_idx[fmask] + s_f[fmask].astype(np.int64) * section)
     fpos = np.flatnonzero(fmask.ravel())
     order = np.argsort(fkey)
     fkey, fpos = fkey[order], fpos[order]
-    r_b, s_b, _ = np.indices(bwd_idx.shape)
+    s_b, r_b, _ = np.indices(bwd_idx.shape)
     bmask = bwd_idx >= 0
     bkey = ((bwd_idx[bmask].astype(np.int64)
              + s_b[bmask].astype(np.int64) * section) * d_in + r_b[bmask])
@@ -444,6 +444,10 @@ def _incrs_stack_init(key, n_stages: int, d_in: int, d_out: int,
         jnp.concatenate([p0.values[None], rest], axis=0), p0.meta)
 
 
+# Bound on the gathered-x block of one ``_stripe_dw`` step.
+_DW_BLOCK_BYTES = 128 * 1024 * 1024
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _incrs_mm(values, x, meta: InCRSLinearMeta):
     """y[T, d_out] = x[T, d_in] @ W, with W^T stored as section stripes."""
@@ -462,29 +466,44 @@ def _stripe_dw(idx: jnp.ndarray, section: int, x, dy) -> jnp.ndarray:
     dW^T[r, c] = sum_t dy[t, r] x[t, c], evaluated ONLY at the live
     non-zeros: gather x's columns by the stripe idx, one T-length MAC per
     stored value — compute scales with nnz, not d_out*d_in. Scanned one
-    section at a time so the gathered-x intermediate peaks at
-    (Op, smax, T), not the whole padded-nnz x T. Shared by the
+    section and one row block at a time so the gathered-x intermediate
+    stays under ``_DW_BLOCK_BYTES``, not the whole padded-nnz x T (one
+    whole section of a 4096 -> 14336 layer at 512 tokens is a 5 GB
+    gather). Shared by the
     single-device and row-sharded VJPs (the sharded one calls it with a
     shard-local ``idx``/``dy`` panel).
     """
-    n_sections = idx.shape[1]
+    n_sections, op, smax = idx.shape
+    t = x.shape[0]
     gcol = jnp.where(
         idx >= 0,
         idx + section * jnp.arange(n_sections,
-                                   dtype=jnp.int32)[None, :, None], 0)
+                                   dtype=jnp.int32)[:, None, None], 0)
     kp = n_sections * section
     xpt = jnp.pad(x.astype(jnp.float32),
                   ((0, 0), (0, kp - x.shape[1]))).T          # (kp, T)
     dyp = jnp.pad(dy.astype(jnp.float32),
-                  ((0, 0), (0, idx.shape[0] - dy.shape[1])))   # (T, Op)
+                  ((0, 0), (0, op - dy.shape[1])))            # (T, Op)
+    # Rows per step: the largest divisor of Op whose gathered-x block
+    # (rows, smax, T) f32 stays under _DW_BLOCK_BYTES.
+    rows = max((r for r in range(1, op + 1) if op % r == 0
+                and r * smax * t * 4 <= _DW_BLOCK_BYTES), default=1)
+    n_chunks = op // rows
+    dyc = dyp.T.reshape(n_chunks, rows, t)           # (chunks, rows, T)
 
-    def section_dw(_, gs):                           # gs: (Op, smax)
-        xg = jnp.take(xpt, gs, axis=0)               # (Op, smax, T)
-        return None, jnp.einsum("rkt,tr->rk", xg, dyp,
+    def chunk_dw(_, c):
+        gs, dyr = c                                  # (rows, smax), (rows, T)
+        xg = jnp.take(xpt, gs, axis=0)               # (rows, smax, T)
+        return None, jnp.einsum("rkt,rt->rk", xg, dyr,
                                 preferred_element_type=jnp.float32)
 
-    _, dvals = jax.lax.scan(section_dw, None, jnp.moveaxis(gcol, 1, 0))
-    return jnp.where(idx >= 0, jnp.moveaxis(dvals, 0, 1), 0.0)
+    def section_dw(_, gs):                           # gs: (Op, smax)
+        _, dv = jax.lax.scan(chunk_dw, None,
+                             (gs.reshape(n_chunks, rows, smax), dyc))
+        return None, dv.reshape(op, smax)
+
+    _, dvals = jax.lax.scan(section_dw, None, gcol)
+    return jnp.where(idx >= 0, dvals, 0.0)
 
 
 def _incrs_mm_bwd(meta, res, dy):
@@ -518,9 +537,9 @@ def incrs_to_dense_weight(p: InCRSLinearParams) -> np.ndarray:
     """Densify W (d_in, d_out) from the CURRENT values, for oracles/tests."""
     idx = np.asarray(p.meta.fwd_idx)
     vals = np.asarray(p.values)
-    wt = np.zeros((idx.shape[0], idx.shape[1] * p.meta.section), np.float32)
-    r, s, k = np.nonzero(idx >= 0)
-    wt[r, idx[r, s, k] + s * p.meta.section] = vals[r, s, k]
+    wt = np.zeros((idx.shape[1], idx.shape[0] * p.meta.section), np.float32)
+    s, r, k = np.nonzero(idx >= 0)
+    wt[r, idx[s, r, k] + s * p.meta.section] = vals[s, r, k]
     return wt[:p.meta.d_out, :p.meta.d_in].T
 
 
@@ -555,9 +574,9 @@ class ShardedInCRSLinearMeta:
     its own panel's metadata. ``eq=False`` -> identity hash/eq, same
     rationale as ``InCRSLinearMeta``.
     """
-    fwd_idx: jnp.ndarray      # (S, Op_s, Si, smax) int32 — W^T shard stripes
-    bwd_idx: jnp.ndarray      # (S, Ip, So_s, smax_t) int32 — W shard stripes
-    t_gather: jnp.ndarray     # (S, Ip*So_s*smax_t) int32 — per-shard bwd
+    fwd_idx: jnp.ndarray      # (S, Si, Op_s, smax) int32 — W^T shard stripes
+    bwd_idx: jnp.ndarray      # (S, So_s, Ip, smax_t) int32 — W shard stripes
+    t_gather: jnp.ndarray     # (S, So_s*Ip*smax_t) int32 — per-shard bwd
     #                           slot -> shard-local flat fwd slot
     d_in: int
     d_out: int
@@ -576,7 +595,7 @@ class ShardedInCRSLinearMeta:
 
 @dataclasses.dataclass
 class ShardedInCRSLinearParams:
-    values: jnp.ndarray       # (S, Op_s, Si, smax) f32 — trainable leaf,
+    values: jnp.ndarray       # (S, Si, Op_s, smax) f32 — trainable leaf,
     #                           NamedSharding over the shard axes
     meta: ShardedInCRSLinearMeta
 
@@ -740,7 +759,7 @@ def _incrs_mm_sharded(values, x, meta: ShardedInCRSLinearMeta):
 
     return shard_map(local, mesh=meta.mesh,
                      in_specs=(P(ax), P(ax), P()),
-                     out_specs=P(None, ax), **SHARD_MAP_KW)(
+                     out_specs=P(None, ax), check_vma=False)(
         values, meta.fwd_idx, x)
 
 
@@ -771,7 +790,7 @@ def _incrs_mm_sharded_bwd(meta, res, dy):
     dvals, dx = shard_map(local, mesh=meta.mesh,
                           in_specs=(P(ax), P(ax), P(ax), P(ax),
                                     P(None, ax), P()),
-                          out_specs=(P(ax), P()), **SHARD_MAP_KW)(
+                          out_specs=(P(ax), P()), check_vma=False)(
         values, meta.fwd_idx, meta.bwd_idx, meta.t_gather, dy, x)
     return dvals.astype(values.dtype), dx.astype(x.dtype)
 
@@ -792,13 +811,13 @@ def _incrs_sharded_apply(p: ShardedInCRSLinearParams,
 def incrs_sharded_to_dense_weight(p: ShardedInCRSLinearParams) -> np.ndarray:
     """Densify W (d_in, d_out) from the CURRENT sharded values (gathers to
     host — for oracles/tests only)."""
-    idx = np.asarray(p.meta.fwd_idx)                 # (S, Op_s, Si, smax)
+    idx = np.asarray(p.meta.fwd_idx)                 # (S, Si, Op_s, smax)
     vals = np.asarray(p.values)
     sw, section = p.meta.shard_width, p.meta.section
-    wt = np.zeros((p.meta.d_out, idx.shape[2] * section), np.float32)
+    wt = np.zeros((p.meta.d_out, idx.shape[1] * section), np.float32)
     for s in range(idx.shape[0]):
-        r, ss, k = np.nonzero(idx[s] >= 0)
-        wt[s * sw + r, idx[s][r, ss, k] + ss * section] = vals[s][r, ss, k]
+        ss, r, k = np.nonzero(idx[s] >= 0)
+        wt[s * sw + r, idx[s][ss, r, k] + ss * section] = vals[s][ss, r, k]
     return wt[:, :p.meta.d_in].T
 
 
@@ -830,33 +849,33 @@ def _bsr_pack_values(meta: SparseLinearMeta, w: np.ndarray) -> jnp.ndarray:
 
 
 def _incrs_pack_values(meta: InCRSLinearMeta, w: np.ndarray) -> jnp.ndarray:
-    """Dense W -> (Op, Si, smax) stripe values of meta's live slots."""
+    """Dense W -> (Si, Op, smax) stripe values of meta's live slots."""
     idx = np.asarray(meta.fwd_idx)
     wt = np.asarray(w, np.float32).T
-    kp = idx.shape[1] * meta.section
-    wtp = np.zeros((idx.shape[0], kp), np.float32)
+    kp = idx.shape[0] * meta.section
+    wtp = np.zeros((idx.shape[1], kp), np.float32)
     wtp[:wt.shape[0], :wt.shape[1]] = wt
     vals = np.zeros(idx.shape, np.float32)
-    r, s, k = np.nonzero(idx >= 0)
-    vals[r, s, k] = wtp[r, idx[r, s, k] + s * meta.section]
+    s, r, k = np.nonzero(idx >= 0)
+    vals[s, r, k] = wtp[r, idx[s, r, k] + s * meta.section]
     return jnp.asarray(vals)
 
 
 def _sharded_pack_values(meta: ShardedInCRSLinearMeta,
                          w: np.ndarray) -> jnp.ndarray:
-    """Dense W -> (S, Rp, Si, smax) per-shard stripe values, placed with
+    """Dense W -> (S, Si, Rp, smax) per-shard stripe values, placed with
     the meta's NamedSharding like the packer's values leaf."""
     idx = np.asarray(meta.fwd_idx)
     wt = np.asarray(w, np.float32).T
     sw, section = meta.shard_width, meta.section
-    kp = idx.shape[2] * section
+    kp = idx.shape[1] * section
     vals = np.zeros(idx.shape, np.float32)
     for s in range(idx.shape[0]):
-        panel = np.zeros((idx.shape[1], kp), np.float32)
+        panel = np.zeros((idx.shape[2], kp), np.float32)
         rows = wt[s * sw:(s + 1) * sw]
         panel[:rows.shape[0], :rows.shape[1]] = rows
-        r, ss, k = np.nonzero(idx[s] >= 0)
-        vals[s][r, ss, k] = panel[r, idx[s][r, ss, k] + ss * section]
+        ss, r, k = np.nonzero(idx[s] >= 0)
+        vals[s][ss, r, k] = panel[r, idx[s][ss, r, k] + ss * section]
     return jax.device_put(jnp.asarray(vals),
                           NamedSharding(meta.mesh, P(meta.axes)))
 

@@ -21,8 +21,8 @@ fused kernel stays the reference oracle (see tests/test_spgemm.py).
 Inputs are per-round padded sparse rows from ``ops.prep_rounds`` for BOTH
 operands (the RHS is sparse too — this is the A[M,K] @ B[N,K].T row-wise
 product formulation, B row-stored like A):
-  idx (rows, n_rounds, rmax) int32 local index in [0, R), -1 = padding
-  val (rows, n_rounds, rmax) values
+  idx (n_rounds, rows, rmax) int32 local index in [0, R), -1 = padding
+  val (n_rounds, rows, rmax) values
 """
 from __future__ import annotations
 
@@ -33,22 +33,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..kernels._compat import CompilerParams
-
-
-def _densify(idx, val, rounds: int):
-    """(rows, rmax) sparse -> (rows, R) dense stripe via one-hot matmul."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rounds), 2)
-    oh = (idx[..., None] == iota).astype(jnp.float32)     # (rows, rmax, R)
-    return jnp.einsum("srk,sr->sk", oh,
-                      val.astype(jnp.float32),
-                      preferred_element_type=jnp.float32)
+from ..kernels.incrs_spmm import _expand_stripe
 
 
 def _condense_kernel(a_idx_ref, a_val_ref, b_idx_ref, b_val_ref, s_ref, *,
                      rounds: int):
-    da = _densify(a_idx_ref[:, 0, :], a_val_ref[:, 0, :], rounds)  # (bm, R)
-    db = _densify(b_idx_ref[:, 0, :], b_val_ref[:, 0, :], rounds)  # (bn, R)
+    da = _expand_stripe(a_idx_ref[0], a_val_ref[0], rounds)  # (bm, R)
+    db = _expand_stripe(b_idx_ref[0], b_val_ref[0], rounds)  # (bn, R)
     s_ref[0, :, :] = jax.lax.dot_general(
         da, db, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -66,8 +57,8 @@ def spgemm_condense(a_idx: jnp.ndarray, a_val: jnp.ndarray,
     first axis (in ascending order — see ``spgemm_merge``) yields
     C = A @ B.T. Fully parallel: each grid step owns its output block.
     """
-    m, n_rounds, rmax_a = a_idx.shape
-    n, n_rounds_b, rmax_b = b_idx.shape
+    n_rounds, m, rmax_a = a_idx.shape
+    n_rounds_b, n, rmax_b = b_idx.shape
     if n_rounds != n_rounds_b:
         raise ValueError(
             f"operand round counts differ: {n_rounds} vs {n_rounds_b}")
@@ -81,15 +72,15 @@ def spgemm_condense(a_idx: jnp.ndarray, a_val: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1, rmax_a), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((bm, 1, rmax_a), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((bn, 1, rmax_b), lambda i, j, t: (j, t, 0)),
-            pl.BlockSpec((bn, 1, rmax_b), lambda i, j, t: (j, t, 0)),
+            pl.BlockSpec((1, bm, rmax_a), lambda i, j, t: (t, i, 0)),
+            pl.BlockSpec((1, bm, rmax_a), lambda i, j, t: (t, i, 0)),
+            pl.BlockSpec((1, bn, rmax_b), lambda i, j, t: (t, j, 0)),
+            pl.BlockSpec((1, bn, rmax_b), lambda i, j, t: (t, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda i, j, t: (t, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_rounds, m, n), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
     )(a_idx, a_val, b_idx, b_val)
 
@@ -134,6 +125,6 @@ def spgemm_merge(stripes: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(stripes)

@@ -49,7 +49,7 @@ def condense_merge_prepped(ai, av, bi, bv, *, rounds: int = 128,
     trim to the real (M, N). Bitwise identical to the fused reference on
     identical inputs.
     """
-    interpret = _ops.INTERPRET if interpret is None else interpret
+    interpret = _ops.resolve_interpret(interpret)
     if out_dtype is None:
         out_dtype = jnp.result_type(av.dtype, bv.dtype)
     rmax = max(ai.shape[2], bi.shape[2])
@@ -59,8 +59,8 @@ def condense_merge_prepped(ai, av, bi, bv, *, rounds: int = 128,
     bi = jnp.pad(bi, ((0, 0), (0, 0), (0, rmax - bi.shape[2])),
                  constant_values=-1)
     bv = jnp.pad(bv, ((0, 0), (0, 0), (0, rmax - bv.shape[2])))
-    m, n_rounds, _ = ai.shape
-    n = bi.shape[0]
+    n_rounds, m, _ = ai.shape
+    n = bi.shape[1]
     if check:
         _check_launch("condense", m=m, n=n, bm=bm, bn=bn, rounds=rounds,
                       n_rounds=n_rounds, rmax_a=rmax, rmax_b=rmax)

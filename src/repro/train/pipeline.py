@@ -15,12 +15,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# shard_map version compat is shared with the sharded InCRS data path
-# (sparse/linear.py, kernels/ops.py); the canonical shim lives next to the
-# kernels. The old names are re-exported here for existing importers.
-from ..kernels._compat import SHARD_MAP_KW as _SHARD_MAP_KW, shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, *, n_stages: int,
@@ -60,8 +56,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, n_stages: int,
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
     return shard_map(
         local, mesh=mesh,
-        in_specs=(pspec, P()), out_specs=P(),
-        **_SHARD_MAP_KW,
+        in_specs=(pspec, P()), out_specs=P(), check_vma=False,
     )(stage_params, x)
 
 

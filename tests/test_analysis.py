@@ -409,7 +409,7 @@ def test_plan_raises_on_infeasible_cached_config(rng, monkeypatch,
     mask = (rng.random((256, 128)) < 0.1)
     p0 = _incrs_plan(rng, 8192, tune="off", mask=mask)
     idx, section = p0._tuning_arrays()
-    key = autotune.cache_key(idx.shape[0], idx.shape[1], idx.shape[2],
+    key = autotune.cache_key(idx.shape[1], idx.shape[0], idx.shape[2],
                              section, 8192,
                              autotune.backend_name(ops.INTERPRET))
     # A poisoned cache entry: reuse at bm=128 holds a 4 MiB row panel at
@@ -593,7 +593,7 @@ def test_plan_rejects_bounds_infeasible_cached_config(
     mask = (rng.random((256, 128)) < 0.1)
     p0 = _incrs_plan(rng, 128, tune="off", mask=mask)
     idx, section = p0._tuning_arrays()
-    key = autotune.cache_key(idx.shape[0], idx.shape[1], idx.shape[2],
+    key = autotune.cache_key(idx.shape[1], idx.shape[0], idx.shape[2],
                              section, 128,
                              autotune.backend_name(ops.INTERPRET))
     autotune._MEM[key] = autotune.TunedConfig("reuse", 128, 128, 1.0, 1.0)

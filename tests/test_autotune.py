@@ -124,7 +124,7 @@ def test_cache_roundtrip_and_invalidation(rng, monkeypatch, tmp_path):
     assert cfg.measured_us > 0 and cfg.predicted_us > 0
     assert cfg.overhead_factor == cfg.measured_us / cfg.predicted_us
 
-    key = autotune.cache_key(prep.idx.shape[0], prep.n_sections,
+    key = autotune.cache_key(prep.padded_rows, prep.n_sections,
                              prep.idx.shape[2], prep.section, b.shape[1],
                              autotune.backend_name(True))
     # Round-trip through disk: forget process state, re-load from file.

@@ -57,15 +57,15 @@ def _loop_prep_sections(incrs, pad_rows_to=8):
             spans[i, s] = (base + prefix, cnt)
             smax = max(smax, cnt)
     mp = -(-m // pad_rows_to) * pad_rows_to
-    idx = np.full((mp, n_sections, smax), -1, dtype=np.int32)
-    val = np.zeros((mp, n_sections, smax), dtype=np.float32)
+    idx = np.full((n_sections, mp, smax), -1, dtype=np.int32)
+    val = np.zeros((n_sections, mp, smax), dtype=np.float32)
     for i in range(m):
         for s in range(n_sections):
             start, cnt = spans[i, s]
             if cnt:
                 cols = crs.col_idx[start:start + cnt]
-                idx[i, s, :cnt] = cols - s * incrs.section
-                val[i, s, :cnt] = crs.values[start:start + cnt]
+                idx[s, i, :cnt] = cols - s * incrs.section
+                val[s, i, :cnt] = crs.values[start:start + cnt]
     return idx, val
 
 
@@ -79,8 +79,8 @@ def _loop_prep_rounds(crs, rounds, rmax=None, pad_rows_to=128):
     rmax = int(counts.max(initial=1)) if rmax is None else rmax
     rmax = max(1, min(rmax, rounds))
     mp = -(-m // pad_rows_to) * pad_rows_to
-    idx = np.full((mp, n_rounds, rmax), -1, dtype=np.int32)
-    val = np.zeros((mp, n_rounds, rmax), dtype=np.float32)
+    idx = np.full((n_rounds, mp, rmax), -1, dtype=np.int32)
+    val = np.zeros((n_rounds, mp, rmax), dtype=np.float32)
     for i in range(m):
         s, e = crs.row_ptr[i], crs.row_ptr[i + 1]
         cols = crs.col_idx[s:e]
@@ -89,8 +89,8 @@ def _loop_prep_rounds(crs, rounds, rmax=None, pad_rows_to=128):
         for rr in np.unique(r):
             sel = r == rr
             slot[sel] = np.arange(sel.sum())
-        idx[i, r, slot] = cols % rounds
-        val[i, r, slot] = crs.values[s:e]
+        idx[r, i, slot] = cols % rounds
+        val[r, i, slot] = crs.values[s:e]
     return idx, val
 
 

@@ -145,9 +145,9 @@ def test_incrs_training_converges(rng):
     live = np.asarray(incrs_to_dense_weight(p)) != 0
     idx = np.asarray(p.meta.fwd_idx)
     opt_vals = np.zeros_like(np.asarray(p.values))
-    r, s, k = np.nonzero(idx >= 0)
+    s, r, k = np.nonzero(idx >= 0)
     wt_true = w_true.T
-    opt_vals[r, s, k] = wt_true[r, idx[r, s, k] + s * p.meta.section]
+    opt_vals[s, r, k] = wt_true[r, idx[s, r, k] + s * p.meta.section]
     floor = float(loss(jnp.asarray(opt_vals)))
 
     vals = p.values

@@ -95,13 +95,29 @@ def test_packer_budget_narrows_waves():
 def test_seed_from_bench(tmp_path):
     import json
     path = tmp_path / "bench.json"
-    path.write_text(json.dumps({"rows": [
+    path.write_text(json.dumps({"backend": "cpu", "interpret": True,
+                                "rows": [
         {"name": "incrs_spmm_fused", "us": 6400.0, "derived": "cols=64"},
         {"name": "dense_mm_256", "us": 99.0, "derived": ""},
     ]}))
-    m = sched.seed_from_bench(str(path))
+    m = sched.seed_from_bench(str(path), "interpret")
     assert m.us_per_col == pytest.approx(100.0)
-    assert sched.seed_from_bench(str(tmp_path / "nope.json")) \
+    assert sched.seed_from_bench(str(tmp_path / "nope.json"),
+                                 "interpret").us_per_col is None
+
+
+def test_seed_from_bench_needs_matching_backend(tmp_path):
+    """An interpreter record never seeds a chip engine's wave packing."""
+    import json
+    path = tmp_path / "bench.json"
+    path.write_text(json.dumps({"backend": "cpu", "interpret": True,
+                                "rows": [{"name": "incrs_spmm_fused",
+                                          "us": 6400.0,
+                                          "derived": "cols=64"}]}))
+    assert sched.seed_from_bench(str(path), "interpret").us_per_col \
+        == pytest.approx(100.0)
+    assert sched.seed_from_bench(str(path), "tpu").us_per_col is None
+    assert sched.seed_cost_model(backend="tpu", bench_path=str(path)) \
         .us_per_col is None
 
 

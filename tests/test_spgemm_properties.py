@@ -26,15 +26,15 @@ def _rand_pair(rng, m, n, k, density):
 
 def _unprep(idx, val, rounds, k):
     """Invert prep_rounds: scatter per-round local slots back to dense."""
-    mp, n_rounds, rmax = idx.shape
+    n_rounds, mp, rmax = idx.shape
     out = np.zeros((mp, k), dtype=np.asarray(val).dtype)
     idx, val = np.asarray(idx), np.asarray(val)
     for t in range(n_rounds):
-        live = idx[:, t, :] >= 0
+        live = idx[t] >= 0
         rows, slots = np.nonzero(live)
-        cols = t * rounds + idx[rows, t, slots]
+        cols = t * rounds + idx[t, rows, slots]
         keep = cols < k
-        out[rows[keep], cols[keep]] = val[rows[keep], t, slots[keep]]
+        out[rows[keep], cols[keep]] = val[t, rows[keep], slots[keep]]
     return out
 
 
@@ -45,7 +45,7 @@ def test_prep_rounds_roundtrip(rng, density, rounds):
     A, _ = _rand_pair(rng, 24, 1, 200, density)
     a = CRS.from_dense(A)
     ai, av = ops.prep_rounds(a, rounds, pad_rows_to=8)
-    assert ai.shape == av.shape and ai.shape[0] % 8 == 0
+    assert ai.shape == av.shape and ai.shape[1] % 8 == 0
     back = _unprep(ai, av, rounds, 200)
     np.testing.assert_array_equal(back[:24], A)
     assert (back[24:] == 0).all()
