@@ -1,0 +1,9 @@
+"""Device: 1 minus the union of device operation intervals over the
+window, from the profiler trace."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
