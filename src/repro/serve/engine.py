@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import time
 import warnings
 from collections import defaultdict, deque
@@ -167,16 +168,111 @@ class _SplitPart:
 
 
 @dataclasses.dataclass
+class WaveTrace:
+    """One wave's entry in ``SpMMEngine.trace``: the host seconds of each of
+    its spans and the counters taken at the same boundaries. Appended when
+    the wave is staged; the dispatch and retire fields stay None until the
+    wave gets there."""
+    cols: int                              # request columns in the wave
+    pad_cols: int                          # zero columns up to the bucket
+    hidden: bool                           # staged while a wave computed
+    h2d_bytes: int = 0                     # the concatenated RHS
+    stage_s: float = 0.0                   # spmm.stage
+    pack_s: float = 0.0                    # spmm.stage.pack
+    put_s: float = 0.0                     # spmm.stage.put
+    dispatch_s: Optional[float] = None     # spmm.dispatch
+    retire_s: Optional[float] = None       # spmm.retire
+    wait_s: Optional[float] = None         # spmm.retire.wait
+    fetch_s: Optional[float] = None        # spmm.retire.fetch
+    copy_s: Optional[float] = None         # spmm.retire.copy
+    d2h_bytes: Optional[int] = None        # the wave's output
+    wall_s: Optional[float] = None         # dispatch -> result on host
+    seq: int = -1
+
+
+@dataclasses.dataclass
+class ItemTrace:
+    """One request's (or split part's) entry in ``SpMMEngine.trace``,
+    appended when its wave is dispatched. A part carries its parent's
+    ``rid``; ``latency_s`` is set on the item that completes its request."""
+    rid: int
+    wave: int                              # seq of the wave it rode
+    part: bool
+    queue_wait_s: Optional[float]          # submit -> dispatch
+    latency_s: Optional[float] = None      # submit -> result on host
+    seq: int = -1
+
+
+# Entries the engine's trace keeps: a 51 s window of decode traffic adds
+# about 10,000 (about 640 waves and 9,700 requests).
+TRACE_CAP = 65536
+
+
+class EngineTrace:
+    """A ring of ``WaveTrace`` and ``ItemTrace`` entries with increasing
+    sequence numbers: past ``cap`` the oldest entries drop, and the
+    numbers keep counting. Staged host seconds are also summed over the
+    engine's whole life."""
+
+    def __init__(self, cap: int = TRACE_CAP):
+        self._ring: Deque[Any] = deque(maxlen=cap)
+        self.next_seq = 0
+        self.stage_s_total = 0.0
+        self.stage_s_hidden = 0.0
+
+    def add(self, entry):
+        entry.seq = self.next_seq
+        self.next_seq += 1
+        self._ring.append(entry)
+        return entry
+
+    def since(self, seq: int) -> List[Any]:
+        """The entries numbered ``seq`` and later that the ring still
+        holds, oldest first."""
+        if not self._ring:
+            return []
+        skip = max(0, seq - self._ring[0].seq)
+        return list(itertools.islice(self._ring, skip, None))
+
+    def waves(self) -> List[WaveTrace]:
+        return [e for e in self._ring if isinstance(e, WaveTrace)]
+
+    def items(self) -> List[ItemTrace]:
+        return [e for e in self._ring if isinstance(e, ItemTrace)]
+
+
+class _Span:
+    """A host span of one wave: a ``jax.profiler.TraceAnnotation`` (it
+    lands in a profiler trace beside the device ops, with the stats
+    ``wave`` and ``cols``) timed on the host clock into ``s``."""
+    __slots__ = ("_ann", "t0", "s")
+
+    def __init__(self, name: str, rec: WaveTrace):
+        self._ann = jax.profiler.TraceAnnotation(name, wave=rec.seq,
+                                                 cols=rec.cols)
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.s = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+
+
+@dataclasses.dataclass
 class _Wave:
     """A packed wave moving through the stage -> dispatch -> retire
     pipeline. ``c`` is the dispatched device array (a future under JAX's
-    async dispatch) once the wave is in flight."""
+    async dispatch) once the wave is in flight; ``rec`` and ``entries``
+    are its entries in the engine's trace (they hold no device array)."""
     items: List[Any]
     b: Any                                 # device-transferred concat RHS
-    prep_s: float                          # host prep wall time
-    hidden: bool                           # prepped while a wave was in flight
+    rec: WaveTrace
     c: Any = None
     t_dispatch: Optional[float] = None
+    entries: List[ItemTrace] = dataclasses.field(default_factory=list)
 
 
 # Wave widths are bucketed (zero-padded) up to this quantum before launch
@@ -283,11 +379,7 @@ class SpMMEngine:
         self.stats: Dict[str, int] = defaultdict(int)
         self._staged: Optional[_Wave] = None
         self._inflight: Optional[_Wave] = None
-        self._wave_wall_s: List[float] = []
-        self._queue_wait_s: List[float] = []
-        self._req_latency_s: List[float] = []
-        self._prep_s_total = 0.0
-        self._prep_s_hidden = 0.0
+        self.trace = EngineTrace()
         self._t_first_submit: Optional[float] = None
         self._t_last_done: Optional[float] = None
 
@@ -484,6 +576,8 @@ class SpMMEngine:
             self.queue.append(req)
 
     # -- pipeline stages ------------------------------------------------
+    # Each stage runs in spans named ``spmm.<stage>[.<part>]`` (``_Span``);
+    # their host seconds and the wave's counters go into ``self.trace``.
     def _stage(self, hidden: bool) -> bool:
         """Pack the next wave off the queue and do ALL its host prep
         (dtype promotion, column concat, device transfer). ``hidden`` says
@@ -492,40 +586,47 @@ class SpMMEngine:
         wave = self.scheduler.next_wave(self.queue, self.max_wave_cols)
         if not wave:
             return False
-        t0 = time.perf_counter()
-        # Promote WITHIN the wave: a bf16 request sharing a wave with f32
-        # neighbours computes at f32, and every request's panel comes back
-        # in ITS OWN dtype. The fused kernel accumulates in f32 — that is
-        # the compute-precision ceiling — so a wider-than-f32 wave (f64
-        # requests) is computed at f32 and says so instead of silently
-        # relabeling f32 numbers as f64.
-        wave_dt = functools.reduce(jnp.promote_types,
-                                   (r.b.dtype for r in wave))
-        if jnp.issubdtype(wave_dt, jnp.floating) and \
-                jnp.finfo(wave_dt).bits > 32:
-            warnings.warn(
-                f"SpMMEngine: wave dtype {np.dtype(wave_dt)} exceeds the "
-                f"fused kernel's f32 accumulation — results carry the "
-                f"request dtype but f32 precision", stacklevel=3)
-        panels = [np.asarray(r.b, dtype=wave_dt) for r in wave]
-        cols = sum(p.shape[1] for p in panels)
+        cols = sum(r.b.shape[1] for r in wave)
         # Bucket the wave width to the lane quantum: packed widths are
         # data-dependent sums, and every DISTINCT width pays a one-time
         # trace/compile cost orders of magnitude above the launch itself.
         # Padding to the next 128-col bucket collapses all waves onto a
         # handful of kernel shapes (the kernel pads to 128-multiples
         # internally anyway, so the zero columns cost no extra compute).
-        bucket = -(-cols // WAVE_QUANTUM) * WAVE_QUANTUM
-        if bucket > cols:
-            panels.append(np.zeros((panels[0].shape[0], bucket - cols),
-                                   dtype=wave_dt))
-            self.stats["pad_cols"] += bucket - cols
-        b = jnp.asarray(np.concatenate(panels, axis=1))
-        prep_s = time.perf_counter() - t0
-        self._prep_s_total += prep_s
+        pad = -(-cols // WAVE_QUANTUM) * WAVE_QUANTUM - cols
+        rec = self.trace.add(WaveTrace(cols, pad, hidden))
+        with _Span("spmm.stage", rec) as stage:
+            with _Span("spmm.stage.pack", rec) as pack:
+                # Promote WITHIN the wave: a bf16 request sharing a wave
+                # with f32 neighbours computes at f32, and every request's
+                # panel comes back in ITS OWN dtype. The fused kernel
+                # accumulates in f32 — that is the compute-precision
+                # ceiling — so a wider-than-f32 wave (f64 requests) is
+                # computed at f32 and says so instead of silently
+                # relabeling f32 numbers as f64.
+                wave_dt = functools.reduce(jnp.promote_types,
+                                           (r.b.dtype for r in wave))
+                if jnp.issubdtype(wave_dt, jnp.floating) and \
+                        jnp.finfo(wave_dt).bits > 32:
+                    warnings.warn(
+                        f"SpMMEngine: wave dtype {np.dtype(wave_dt)} exceeds "
+                        f"the fused kernel's f32 accumulation — results "
+                        f"carry the request dtype but f32 precision",
+                        stacklevel=3)
+                panels = [np.asarray(r.b, dtype=wave_dt) for r in wave]
+                if pad:
+                    panels.append(np.zeros((panels[0].shape[0], pad),
+                                           dtype=wave_dt))
+                host_b = np.concatenate(panels, axis=1)
+            with _Span("spmm.stage.put", rec) as put:
+                b = jnp.asarray(host_b)
+        self.stats["pad_cols"] += pad
+        rec.h2d_bytes = host_b.nbytes
+        rec.stage_s, rec.pack_s, rec.put_s = stage.s, pack.s, put.s
+        self.trace.stage_s_total += stage.s
         if hidden:
-            self._prep_s_hidden += prep_s
-        self._staged = _Wave(wave, b, prep_s, hidden)
+            self.trace.stage_s_hidden += stage.s
+        self._staged = _Wave(wave, b, rec)
         return True
 
     def _dispatch(self) -> None:
@@ -536,17 +637,19 @@ class SpMMEngine:
         if w is None:
             return
         self._staged = None
-        t0 = time.perf_counter()
-        if self._bound is not None:
-            w.c = self._bound(w.b, variant=self.variant,
-                              interpret=self.interpret)
-        else:
-            w.c = self._ops.spmm(self.prep, w.b, variant=self.variant,
-                                 interpret=self.interpret)
-        w.t_dispatch = t0
-        for r in w.items:
-            if r.t_submit is not None:
-                self._queue_wait_s.append(t0 - r.t_submit)
+        with _Span("spmm.dispatch", w.rec) as span:
+            if self._bound is not None:
+                w.c = self._bound(w.b, variant=self.variant,
+                                  interpret=self.interpret)
+            else:
+                w.c = self._ops.spmm(self.prep, w.b, variant=self.variant,
+                                     interpret=self.interpret)
+        w.rec.dispatch_s = span.s
+        w.t_dispatch = t0 = span.t0
+        w.entries = [self.trace.add(ItemTrace(
+            r.rid, w.rec.seq, isinstance(r, _SplitPart),
+            None if r.t_submit is None else t0 - r.t_submit))
+            for r in w.items]
         self._inflight = w
 
     def _finish_item(self, r, panel: np.ndarray, t_done: float) -> None:
@@ -562,8 +665,6 @@ class SpMMEngine:
             r.out = panel.astype(r.b.dtype)
         r.done = True
         r.t_done = t_done
-        if r.t_submit is not None:
-            self._req_latency_s.append(t_done - r.t_submit)
         self.stats["requests"] += 1
         self.finished.append(r)
 
@@ -575,19 +676,42 @@ class SpMMEngine:
         if w is None:
             return
         self._inflight = None
-        c = np.asarray(w.c)                # blocks until the device is done
-        t_done = time.perf_counter()
-        wall_s = t_done - w.t_dispatch
-        off = 0
-        for r in w.items:
-            width = r.b.shape[1]
-            self._finish_item(r, c[:, off:off + width], t_done)
-            off += width
+        rec = w.rec
+        with _Span("spmm.retire", rec) as whole:
+            with _Span("spmm.retire.wait", rec) as wait:
+                jax.block_until_ready(w.c)     # the device finishes
+            with _Span("spmm.retire.fetch", rec) as fetch:
+                c = np.asarray(w.c)            # device -> host copy
+            t_done = time.perf_counter()
+            with _Span("spmm.retire.copy", rec) as copy:
+                off = 0
+                for r, entry in zip(w.items, w.entries):
+                    width = r.b.shape[1]
+                    self._finish_item(r, c[:, off:off + width], t_done)
+                    off += width
+                    req = r.parent if isinstance(r, _SplitPart) else r
+                    if req.done and req.t_submit is not None:
+                        entry.latency_s = t_done - req.t_submit
+        rec.wall_s = t_done - w.t_dispatch
+        rec.d2h_bytes = c.nbytes
+        rec.retire_s, rec.wait_s, rec.fetch_s, rec.copy_s = \
+            whole.s, wait.s, fetch.s, copy.s
         self.stats["cols"] += off
         self.stats["waves"] += 1
-        self._wave_wall_s.append(wall_s)
         self._t_last_done = t_done
-        self.scheduler.observe(off, wall_s * 1e6)
+        self.scheduler.observe(off, rec.wall_s * 1e6)
+
+    # ``perfbench/harness.py`` reads these two lists by index around its
+    # window. They are views of ``trace``, in the order each list used to
+    # grow: queue waits at dispatch, wave wall times at retire.
+    @property
+    def _queue_wait_s(self) -> List[float]:
+        return [e.queue_wait_s for e in self.trace.items()
+                if e.queue_wait_s is not None]
+
+    @property
+    def _wave_wall_s(self) -> List[float]:
+        return [e.wall_s for e in self.trace.waves() if e.wall_s is not None]
 
     # -- serving loop ----------------------------------------------------
     def step(self, retire: bool = True) -> bool:
@@ -617,16 +741,19 @@ class SpMMEngine:
 
     # -- reporting -------------------------------------------------------
     def stats_summary(self) -> Dict[str, Any]:
-        """Latency/throughput digest over everything served so far:
-        requests/sec, per-request latency and queue-wait p50/p99, per-wave
-        wall p50/p99, and how much host prep the overlap pipeline hid.
-        ``serve_bench`` records exactly this."""
+        """Latency/throughput digest: requests/sec, per-request latency and
+        queue-wait p50/p99, per-wave wall p50/p99, and how much host prep
+        the overlap pipeline hid. Counts and prep seconds cover everything
+        served so far; the percentiles cover the entries ``trace`` holds
+        (its last ``TRACE_CAP``). ``serve_bench`` records exactly this."""
         elapsed = 0.0
         if self._t_first_submit is not None \
                 and self._t_last_done is not None:
             elapsed = max(0.0, self._t_last_done - self._t_first_submit)
         n = int(self.stats["requests"])
         cost = self.scheduler.cost
+        items = self.trace.items()
+        prep_s, hidden_s = self.trace.stage_s_total, self.trace.stage_s_hidden
         return {
             "mode": "continuous" if self.continuous else "wave_barrier",
             "requests": n,
@@ -634,14 +761,14 @@ class SpMMEngine:
             "cols": int(self.stats["cols"]),
             "elapsed_s": elapsed,
             "requests_per_s": (n / elapsed) if elapsed > 0 else 0.0,
-            "latency_ms": _percentiles_ms(self._req_latency_s),
+            "latency_ms": _percentiles_ms(
+                [e.latency_s for e in items if e.latency_s is not None]),
             "queue_wait_ms": _percentiles_ms(self._queue_wait_s),
             "wave_ms": _percentiles_ms(self._wave_wall_s),
-            "prep_s_total": self._prep_s_total,
-            "prep_s_hidden": self._prep_s_hidden,
-            "prep_overlap_fraction":
-                (self._prep_s_hidden / self._prep_s_total)
-                if self._prep_s_total > 0 else 0.0,
+            "prep_s_total": prep_s,
+            "prep_s_hidden": hidden_s,
+            "prep_overlap_fraction": (hidden_s / prep_s) if prep_s > 0
+            else 0.0,
             "cost_model": {
                 "us_per_col": cost.us_per_col,
                 "launch_overhead_us": cost.launch_overhead_us,

@@ -1,7 +1,8 @@
 """Continuous-batching SpMM engine: cost-model wave packing (skip-scan
 head-of-line fix, latency-budget targeting), oversized-request splitting,
 prep/compute overlap accounting, mid-stream pattern swaps, stats_summary,
-and the multi-tenant LRU pool."""
+the engine's spans and its bounded trace record, and the multi-tenant LRU
+pool."""
 import numpy as np
 import pytest
 
@@ -239,6 +240,134 @@ def test_engine_stats_summary_shape(rng):
         assert s[key]["p99"] >= s[key]["p50"] >= 0.0
     cm = s["cost_model"]
     assert cm["us_per_col"] is not None and cm["n_observed"] >= 1
+    # The keys every reader of the summary uses, computed from the trace.
+    assert set(s) == {"mode", "requests", "waves", "cols", "elapsed_s",
+                      "requests_per_s", "latency_ms", "queue_wait_ms",
+                      "wave_ms", "prep_s_total", "prep_s_hidden",
+                      "prep_overlap_fraction", "cost_model"}
+    assert set(cm) == {"us_per_col", "launch_overhead_us", "n_observed",
+                       "source", "last_target_cols"}
+    waves = eng.trace.waves()
+    assert s["prep_s_total"] == pytest.approx(sum(w.stage_s for w in waves))
+    lat = [e.latency_s for e in eng.trace.items()]
+    assert s["latency_ms"]["p99"] == pytest.approx(max(lat) * 1e3)
+
+
+# ----------------------------------------------------------------------
+# The engine's trace: spans and counters of each wave, one bounded record.
+SPANS = ("spmm.stage", "spmm.stage.pack", "spmm.stage.put", "spmm.dispatch",
+         "spmm.retire", "spmm.retire.wait", "spmm.retire.fetch",
+         "spmm.retire.copy")
+CHILDREN = {"spmm.stage.pack": "spmm.stage", "spmm.stage.put": "spmm.stage",
+            "spmm.retire.wait": "spmm.retire",
+            "spmm.retire.fetch": "spmm.retire",
+            "spmm.retire.copy": "spmm.retire"}
+
+
+def test_engine_trace_counts_each_wave(rng):
+    from repro.serve.engine import ItemTrace, WaveTrace
+    d = _random_sparse(rng, 24, 300, 0.1)
+    eng = SpMMEngine(InCRS.from_dense(d), max_wave_cols=64)
+    wide = SpMMRequest(7, rng.normal(size=(300, 150)).astype(np.float32))
+    narrow = SpMMRequest(8, rng.normal(size=(300, 10)).astype(np.float32))
+    eng.submit(wide)
+    eng.submit(narrow)
+    done = eng.run()
+    _check_outputs(done, d)
+    entries = eng.trace.since(0)
+    assert [e.seq for e in entries] == list(range(len(entries)))
+    waves = [e for e in entries if isinstance(e, WaveTrace)]
+    items = [e for e in entries if isinstance(e, ItemTrace)]
+    assert len(waves) == eng.stats["waves"]
+    assert sum(w.cols for w in waves) == eng.stats["cols"] == 160
+    assert sum(w.pad_cols for w in waves) == eng.stats["pad_cols"]
+    for w in waves:
+        width = w.cols + w.pad_cols
+        assert width % 128 == 0 and w.pad_cols < 128
+        assert w.h2d_bytes == 300 * width * 4
+        assert w.d2h_bytes == 24 * width * 4
+        mine = [i for i in items if i.wave == w.seq]
+        assert mine and w.seq < min(i.seq for i in mine)
+        for part in ("stage", "pack", "put", "dispatch", "retire", "wait",
+                     "fetch", "copy"):
+            assert getattr(w, part + "_s") >= 0.0
+        assert w.pack_s + w.put_s <= w.stage_s
+        assert w.wait_s + w.fetch_s + w.copy_s <= w.retire_s
+        assert w.wall_s >= w.fetch_s
+    assert [w.hidden for w in waves] == [False] + [True] * (len(waves) - 1)
+    # Three parts of the wide request, each with its parent's rid; the
+    # latency lands on the part that completed it.
+    parts = [i for i in items if i.part]
+    assert [i.rid for i in parts] == [7, 7, 7]
+    assert [i.latency_s is not None for i in parts] == [False, False, True]
+    assert [i.rid for i in items if not i.part] == [8]
+    assert all(i.queue_wait_s >= 0.0 for i in items)
+    assert eng.stats_summary()["prep_s_hidden"] == pytest.approx(
+        sum(w.stage_s for w in waves if w.hidden))
+    assert len({i.wave for i in parts}) == 3
+
+
+def test_engine_spans_reach_the_profiler(rng, tmp_path):
+    """Under ``jax.profiler.trace`` each retired wave leaves exactly one of
+    each span, with its ``wave`` and ``cols``: stage, then dispatch, then
+    retire, each child inside its parent."""
+    import glob
+    import jax
+    d = _random_sparse(rng, 16, 200, 0.1)
+    eng = SpMMEngine(InCRS.from_dense(d), max_wave_cols=64)
+    for r in _reqs(rng, 200, [40, 40, 100, 8]):
+        eng.submit(r)
+    with jax.profiler.trace(str(tmp_path)):
+        done = eng.run()
+    _check_outputs(done, d)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path[0])
+    by_wave = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("spmm."):
+                    st = {k: v for k, v in e.stats}
+                    by_wave.setdefault(st["wave"], []).append(
+                        (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         st["cols"]))
+    waves = {w.seq: w for w in eng.trace.waves()}
+    assert set(by_wave) == set(waves)
+    assert sum(len(v) for v in by_wave.values()) == len(SPANS) * len(waves)
+    for seq, evs in by_wave.items():
+        assert sorted(n for n, *_ in evs) == sorted(SPANS)
+        assert {c for *_, c in evs} == {waves[seq].cols}
+        at = {n: (a, b) for n, a, b, _ in evs}
+        assert at["spmm.stage"][1] <= at["spmm.dispatch"][0]
+        assert at["spmm.dispatch"][1] <= at["spmm.retire"][0]
+        for child, parent in CHILDREN.items():
+            assert at[parent][0] <= at[child][0] <= at[child][1] \
+                <= at[parent][1]
+
+
+def test_engine_trace_is_a_bounded_ring(rng):
+    from repro.serve.engine import TRACE_CAP, EngineTrace, ItemTrace
+    ring = EngineTrace(cap=4)
+    for i in range(10):
+        ring.add(ItemTrace(i, 0, False, 0.0))
+    assert [e.seq for e in ring.since(0)] == [6, 7, 8, 9]
+    assert [e.rid for e in ring.since(8)] == [8, 9]
+    assert ring.since(10) == [] and ring.next_seq == 10
+    assert EngineTrace()._ring.maxlen == TRACE_CAP
+    # An engine past its cap keeps serving and counting.
+    d = _random_sparse(rng, 16, 200, 0.1)
+    eng = SpMMEngine(InCRS.from_dense(d), max_wave_cols=32)
+    eng.trace = EngineTrace(cap=8)
+    reqs = _reqs(rng, 200, [32] * 6)
+    for r in reqs:
+        eng.submit(r)
+    done = eng.run()
+    _check_outputs(done, d)
+    assert eng.trace.next_seq == 12            # six waves, six requests
+    assert [e.seq for e in eng.trace.since(0)] == list(range(4, 12))
+    s = eng.stats_summary()
+    assert s["requests"] == 6 and s["waves"] == 6
+    assert s["prep_s_total"] >= sum(w.stage_s for w in eng.trace.waves())
 
 
 def test_engine_latency_budget_caps_wave_width(rng):
