@@ -1,8 +1,8 @@
 """Continuous-batching SpMM engine: cost-model wave packing (skip-scan
 head-of-line fix, latency-budget targeting), oversized-request splitting,
 prep/compute overlap accounting, mid-stream pattern swaps, stats_summary,
-the engine's spans and its bounded trace record, and the multi-tenant LRU
-pool."""
+the engine's spans and its bounded trace record, the staging ring that
+waves are packed into, and the multi-tenant LRU pool."""
 import numpy as np
 import pytest
 
@@ -244,7 +244,9 @@ def test_engine_stats_summary_shape(rng):
     assert set(s) == {"mode", "requests", "waves", "cols", "elapsed_s",
                       "requests_per_s", "latency_ms", "queue_wait_ms",
                       "wave_ms", "prep_s_total", "prep_s_hidden",
-                      "prep_overlap_fraction", "cost_model"}
+                      "prep_overlap_fraction", "stage_buf_allocs",
+                      "stage_buf_reuses", "cost_model"}
+    assert (s["stage_buf_allocs"], s["stage_buf_reuses"]) == (1, 0)
     assert set(cm) == {"us_per_col", "launch_overhead_us", "n_observed",
                        "source", "last_target_cols"}
     waves = eng.trace.waves()
@@ -395,6 +397,129 @@ def test_engine_step_retire_false_leaves_wave_in_flight(rng):
     assert eng._inflight is not None and not eng.finished
     eng.run()
     assert len(eng.finished) == 2 and eng._inflight is None
+
+
+# ----------------------------------------------------------------------
+# The staging ring: every wave is packed into one of two reused host
+# buffers of its dtype.
+def _watch_staging(eng, monkeypatch):
+    """Fail if a wave's host buffer changes between its put and its
+    retire, the time a device transfer from it could still be reading it
+    (on the CPU ``jnp.asarray`` copies at once, so a slot overwritten too
+    early would not show in the outputs alone)."""
+    import jax.numpy as jnp
+    real = jnp.asarray
+    sent = {}
+
+    def asarray(x, *args, **kw):
+        out = real(x, *args, **kw)
+        if isinstance(x, np.ndarray):
+            sent[id(out)] = (out, x, x.copy())
+        return out
+
+    monkeypatch.setattr(jnp, "asarray", asarray)
+    retire = eng._retire
+
+    def checked_retire():
+        if eng._inflight is not None:
+            _, view, snap = sent[id(eng._inflight.b)]
+            np.testing.assert_array_equal(view, snap)
+        retire()
+
+    eng._retire = checked_retire
+    return sent
+
+
+def _ring_mix(rng, k):
+    """At a 128-column cap: a full f32 wave, a 300-column f32 request split
+    into 128 + 128 + 44, its last part with a bf16 and an f32 request (an
+    f32 wave, padded), two bf16 requests (a bf16 wave, padded), and a
+    100-column f32 request (a padded last bucket)."""
+    import jax.numpy as jnp
+    spec = [(128, np.float32), (300, np.float32), (40, jnp.bfloat16),
+            (30, np.float32), (64, jnp.bfloat16), (50, jnp.bfloat16),
+            (100, np.float32)]
+    return [SpMMRequest(i, rng.normal(size=(k, w)).astype(dt))
+            for i, (w, dt) in enumerate(spec)]
+
+
+def _check_mixed_outputs(reqs, d):
+    for r in reqs:
+        assert r.done and r.out.dtype == r.b.dtype
+        want = d @ r.b.astype(np.float32)
+        tol = 1e-4 if r.b.dtype == np.float32 else 2e-2
+        np.testing.assert_allclose(r.out.astype(np.float32), want,
+                                   rtol=tol, atol=tol * np.abs(want).max())
+
+
+def test_engine_staging_ring_serves_consecutive_waves(rng, monkeypatch):
+    """Six consecutive waves through two slots a dtype: full, split,
+    mixed bf16/f32, bf16 and padded waves all match the plain product, and
+    no wave's buffer is written while the wave is in flight."""
+    d = _random_sparse(rng, 24, 300, 0.1)
+    eng = SpMMEngine(InCRS.from_dense(d), max_wave_cols=128)
+    _watch_staging(eng, monkeypatch)
+    reqs = _ring_mix(rng, 300)
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    _check_mixed_outputs(reqs, d)
+    waves = eng.trace.waves()
+    assert [w.cols for w in waves] == [128, 128, 128, 114, 114, 100]
+    assert [w.pad_cols for w in waves] == [0, 0, 0, 14, 14, 28]
+    # Every wave is f32 but the fifth, which is bf16 alone.
+    assert [w.h2d_bytes // (300 * 128) for w in waves] == [4, 4, 4, 4, 2, 4]
+    assert eng.stats["split_parts"] == 3
+
+
+def test_engine_staging_ring_across_a_swap(rng, monkeypatch):
+    """``step(retire=False)``, then ``swap_pattern``, then more waves: the
+    wave in flight keeps its operand and its buffer; later waves reuse the
+    slots on the new operand."""
+    d1 = _random_sparse(rng, 24, 300, 0.1)
+    d2 = _random_sparse(np.random.default_rng(11), 24, 300, 0.1)
+    eng = SpMMEngine(InCRS.from_dense(d1), max_wave_cols=128)
+    _watch_staging(eng, monkeypatch)
+    reqs = _ring_mix(rng, 300)
+    for r in reqs:
+        eng.submit(r)
+    assert eng.step(retire=False)          # wave 0 on d1; wave 1 staged
+    assert eng._inflight is not None and eng._staged is not None
+    eng.swap_pattern(InCRS.from_dense(d2))
+    eng.run()
+    _check_mixed_outputs(reqs[:1], d1)
+    _check_mixed_outputs(reqs[1:], d2)
+    more = _ring_mix(rng, 300)
+    for r in more:
+        eng.submit(r)
+    eng.run()
+    _check_mixed_outputs(more, d2)
+    assert eng.stats["pattern_swaps"] == 1
+    assert eng.stats["stage_buf_allocs"] == 2
+
+
+@pytest.mark.parametrize("continuous", [True, False])
+def test_engine_staging_ring_counters(rng, continuous):
+    """One buffer pair a wave dtype, made on its first wave: allocations
+    equal the distinct wave dtypes, reuses every other wave."""
+    d = _random_sparse(rng, 24, 300, 0.1)
+    eng = SpMMEngine(InCRS.from_dense(d), max_wave_cols=128,
+                     continuous=continuous)
+    for r in _ring_mix(rng, 300):
+        eng.submit(r)
+    eng.run()
+    waves = eng.trace.waves()
+    # f32 first, bf16 at its first bf16-only wave.
+    first = {0, [w.h2d_bytes for w in waves].index(300 * 128 * 2)}
+    assert [w.reused for w in waves] == [i not in first
+                                         for i in range(len(waves))]
+    s = eng.stats_summary()
+    assert s["stage_buf_allocs"] == eng.stats["stage_buf_allocs"] == 2
+    assert s["stage_buf_reuses"] == eng.stats["stage_buf_reuses"] \
+        == s["waves"] - 2
+    assert sorted(str(dt) for dt in eng._rings) == ["bfloat16", "float32"]
+    for ring in eng._rings.values():
+        assert [f.size for f in ring.flats] == [300 * 128] * 2
 
 
 # ----------------------------------------------------------------------
